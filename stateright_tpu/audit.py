@@ -1,6 +1,6 @@
 """Host-side integrity audit of a device checker's visited set.
 
-Motivation (round-3 finding, BASELINE.md): the one on-chip paxos 2c/3s run
+Motivation (a finding on an earlier chip setup): the one on-chip paxos 2c/3s run
 recorded 17,198 unique states where the pinned oracle says 16,668 — on a
 revision whose CPU run reproduces the oracle exactly. Exact state counts
 are this framework's correctness contract (the reference asserts them in

@@ -75,7 +75,7 @@ class DeviceOnDemandChecker(XlaChecker):
 
     def check_states(self, states) -> None:
         """Batched :meth:`check_state`: all pending entries among ``states``
-        expand in one device dispatch per depth group — one tunnel
+        expand in one device dispatch per depth group — one host
         round-trip where per-child expansion would pay one per state (the
         Explorer expands every child of a clicked state)."""
         if not self._waiting:

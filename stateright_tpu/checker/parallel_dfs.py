@@ -161,7 +161,7 @@ class ParallelDfsChecker(Checker):
                     # state.
                     pops += 1
                     # Unlocked fullness pre-check (benign stale read under
-                    # CPython, ADVICE r4): a full market skips the cv
+                    # CPython): a full market skips the cv
                     # acquire entirely; the locked re-check stays
                     # authoritative.
                     if (

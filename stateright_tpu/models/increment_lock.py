@@ -208,12 +208,11 @@ def main(argv=None) -> None:
     from ..report import WriteReporter
 
     args = list(sys.argv[1:] if argv is None else argv)
-    orig_args = list(args)
     cmd = args.pop(0) if args else None
     if cmd in ("check", "check-xla"):
-        from ..backend import guarded_main
+        from ..backend import configure_compile_cache
 
-        guarded_main("stateright_tpu.models.increment_lock", orig_args)
+        configure_compile_cache()
         thread_count = int(args.pop(0)) if args else 3
         print(f"Model checking increment_lock with {thread_count} threads on XLA.")
         PackedIncrementLock(thread_count).checker().spawn_xla(

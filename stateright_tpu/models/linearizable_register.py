@@ -1284,7 +1284,6 @@ def main(argv=None) -> None:
     from ..report import WriteReporter
 
     args = list(sys.argv[1:] if argv is None else argv)
-    orig_args = list(args)
     cmd = args.pop(0) if args else None
     if cmd in ("check", "check-xla"):
         # ``check`` runs the device (XLA) engine on the packed ABD model —
@@ -1298,17 +1297,15 @@ def main(argv=None) -> None:
         # "unordered" / "unordered_nonduplicating" both spell the packed
         # models' default network: naming the default explicitly must
         # route to the SAME device check as omitting it — never a
-        # different engine/state space under the user (ADVICE r4).
+        # different engine/state space under the user.
         if netname == "unordered":
             netname = "unordered_nonduplicating"
         if client_count in (2, 3) and netname in (
             None, "unordered_nonduplicating", "ordered",
         ):
-            from ..backend import guarded_main
+            from ..backend import configure_compile_cache
 
-            guarded_main(
-                "stateright_tpu.models.linearizable_register", orig_args
-            )
+            configure_compile_cache()
             cls = PackedAbdOrdered if netname == "ordered" else PackedAbd
             print(
                 f"Model checking a linearizable register with {client_count} "

@@ -765,7 +765,6 @@ def main(argv=None) -> None:
     from ..report import WriteReporter
 
     args = list(sys.argv[1:] if argv is None else argv)
-    orig_args = list(args)
     cmd = args.pop(0) if args else None
     if cmd in ("check", "check-xla"):
         # ``check`` runs the device (XLA) engine — the reference's check
@@ -774,9 +773,9 @@ def main(argv=None) -> None:
         client_count = int(args.pop(0)) if args else 2
         network = Network.from_name(args.pop(0)) if args else None
         if network is None:
-            from ..backend import guarded_main
+            from ..backend import configure_compile_cache
 
-            guarded_main("stateright_tpu.models.paxos", orig_args)
+            configure_compile_cache()
             print(
                 f"Model checking Single Decree Paxos with {client_count} "
                 "clients on XLA."

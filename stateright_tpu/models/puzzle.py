@@ -135,7 +135,6 @@ def main(argv=None) -> None:
     from ..report import WriteReporter
 
     args = list(sys.argv[1:] if argv is None else argv)
-    orig_args = list(args)
     cmd = args.pop(0) if args else None
 
     def pop_board():
@@ -153,9 +152,9 @@ def main(argv=None) -> None:
         return [1, 4, 2, 3, 5, 8, 6, 7, 0], 3
 
     if cmd == "check":
-        from ..backend import guarded_main
+        from ..backend import configure_compile_cache
 
-        guarded_main("stateright_tpu.models.puzzle", orig_args)
+        configure_compile_cache()
         board, side = pop_board()
         print("Model checking the sliding puzzle on XLA.")
         PackedPuzzle(board, side).checker().spawn_xla(

@@ -156,7 +156,7 @@ class PackedSingleCopyRegister(reg.PackedClientsMixin, PackedModelAdapter):
         if not device_exact:
             self.host_verified_properties = frozenset({self._prop_name})
             # The sampled pass's pattern budget is the cliff's tuning
-            # knob (VERDICT r4 weak #6): more sampled patterns = fewer
+            # knob: more sampled patterns = fewer
             # device false alarms (host confirmations) but a bigger
             # compile and a wider per-level pipeline. tools/hv_cliff.py
             # characterizes the trade; 20k is the shipped default.
@@ -632,7 +632,6 @@ def main(argv=None) -> None:
     from ..report import WriteReporter
 
     args = list(sys.argv[1:] if argv is None else argv)
-    orig_args = list(args)
     cmd = args.pop(0) if args else None
     if cmd in ("check", "check-xla"):
         # ``check`` runs the device (XLA) engine — the reference's check
@@ -641,9 +640,9 @@ def main(argv=None) -> None:
         client_count = int(args.pop(0)) if args and args[0].isdigit() else 2
         netname = args.pop(0) if args else None
         if netname in (None, "ordered"):
-            from ..backend import guarded_main
+            from ..backend import configure_compile_cache
 
-            guarded_main("stateright_tpu.models.single_copy_register", orig_args)
+            configure_compile_cache()
             print(
                 f"Model checking a single-copy register with {client_count} "
                 "clients on XLA."

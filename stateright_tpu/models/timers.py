@@ -264,7 +264,6 @@ def main(argv=None) -> None:
     from ..report import WriteReporter
 
     args = list(sys.argv[1:] if argv is None else argv)
-    orig_args = list(args)
     cmd = args.pop(0) if args else None
     if cmd in ("check", "check-xla"):
         # ``check`` runs the device (XLA) engine; custom network semantics
@@ -272,9 +271,9 @@ def main(argv=None) -> None:
         # default network).
         netname = args.pop(0) if args else None
         if netname is None:
-            from ..backend import guarded_main
+            from ..backend import configure_compile_cache
 
-            guarded_main("stateright_tpu.models.timers", orig_args)
+            configure_compile_cache()
             print("Model checking Pingers on XLA (bounded to 100k states).")
             (
                 PackedTimers(3)

@@ -371,12 +371,11 @@ def main(argv=None) -> None:
     from ..report import WriteReporter
 
     args = list(sys.argv[1:] if argv is None else argv)
-    orig_args = list(args)
     cmd = args.pop(0) if args else None
     if cmd in ("check", "check-xla"):
-        from ..backend import guarded_main
+        from ..backend import configure_compile_cache
 
-        guarded_main("stateright_tpu.models.two_phase_commit", orig_args)
+        configure_compile_cache()
         rm_count = int(args.pop(0)) if args else 2
         print(
             f"Checking two phase commit with {rm_count} resource managers "

@@ -1,10 +1,11 @@
 """Native (C++) host runtime: fingerprinting and parent-map indexing.
 
-The shared library builds lazily from ``hostkit.cpp`` on first import (g++,
-no external deps; pybind11 is unavailable in this image so the binding is
-ctypes over a C ABI). Everything degrades to the pure-Python mirrors when a
-toolchain is missing, so the native layer is an accelerator, never a
-requirement.
+The shared library builds lazily from ``hostkit.cpp`` on first use (g++,
+no external deps; the binding is ctypes over a C ABI). The built file is
+named by a hash of the source's contents, so only a library built from the
+committed ``hostkit.cpp`` is ever loaded. Everything degrades to the
+pure-Python mirrors when the build fails — the failure is written to stderr
+— so the native layer is an accelerator, never a requirement.
 
 Exposed surface:
 
@@ -19,8 +20,10 @@ Exposed surface:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional, Tuple
 
@@ -28,26 +31,36 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "hostkit.cpp")
-_LIB_PATH = os.path.join(_DIR, "libhostkit.so")
+
+
+def _lib_path() -> str:
+    with open(_SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libhostkit-{digest}.so")
+
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
 
 
-def _build() -> bool:
+def _build(lib_path: str) -> bool:
     # Compile to a process-unique temp name and rename into place: rename is
     # atomic, so concurrent builders (or an interrupted compile) can never
     # leave a truncated .so behind.
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
         if proc.returncode != 0:
+            print(f"stateright_tpu.native: build failed, using the Python "
+                  f"mirrors:\n{proc.stderr[-2000:]}", file=sys.stderr)
             return False
-        os.replace(tmp, _LIB_PATH)
+        os.replace(tmp, lib_path)
         return True
-    except Exception:
+    except Exception as e:
+        print(f"stateright_tpu.native: build failed, using the Python "
+              f"mirrors: {type(e).__name__}: {e}", file=sys.stderr)
         return False
     finally:
         if os.path.exists(tmp):
@@ -64,15 +77,15 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         if _build_failed:
             return None
-        if not os.path.exists(_LIB_PATH) or os.path.getmtime(
-            _LIB_PATH
-        ) < os.path.getmtime(_SRC):
-            if not _build():
-                _build_failed = True
-                return None
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path) and not _build(lib_path):
+            _build_failed = True
+            return None
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
+            lib = ctypes.CDLL(lib_path)
+        except OSError as e:
+            print(f"stateright_tpu.native: cannot load {lib_path}, using "
+                  f"the Python mirrors: {e}", file=sys.stderr)
             _build_failed = True
             return None
 

@@ -8,7 +8,7 @@ A sort is O(n log^2 n) data passes; these kernels are O(n): TPU pallas
 grids execute blocks SEQUENTIALLY on a core, so a running output offset
 lives in SMEM scratch across grid steps and every HBM write is a
 contiguous, B-aligned chunk DMA — no scatters
-(docs/backend_pathologies.md #2/#5 never enter the picture).
+(docs/backend_pathologies.md #2 never enters the picture).
 
 Mosaic constrains the design twice over (registry #6 and the r5e
 first-silicon compile): there is no ``cumsum`` lowering inside TC
@@ -163,8 +163,17 @@ def _ring_update(mask_ref, plane_refs, stage, p, B: int):
     return n_b
 
 
+#: The engine's default block (``STPU_PALLAS_BLOCK``): the TPU compiler
+#: refuses 512, whose lane blocks do not match XLA's 1024-element tiling
+#: of the 1-D mask operand ("Try changing your kernel block shape to
+#: (1024)"), and compiles 1024 for a described v5e
+#: (tests/test_tpu_compile.py). Smaller blocks run in interpret mode only.
+DEFAULT_BLOCK = 1024
+
+
 def compact_pallas_staged(
-    mask, planes, cap: int, *, block: int = 512, interpret: bool = False
+    mask, planes, cap: int, *, block: int = DEFAULT_BLOCK,
+    interpret: bool = False,
 ):
     """Order-preserving stream compaction of P uint32 lanes [M] by
     ``mask`` [M] into [P, cap] (HBM output): survivors stream through a

@@ -4,7 +4,7 @@ The round-2 visited set (``ops/hashset.py``) is an open-addressing table
 whose batched insert runs claim-election rounds of gathers and scatters.
 That shape is right for CPUs and wrong for TPUs: XLA:TPU executes the
 per-round scatters effectively serially, and the on-chip cost model
-(BASELINE.md, ``tpu_microbench.log``) measured the insert at 0.24 M ins/s
+(an earlier chip setup, to be re-measured) measured the insert at 0.24 M ins/s
 for a 2^22 batch — 17.3 seconds — while ``lax.sort`` moved the same batch
 in ~3 ms.  On a TPU, **sort is the hash table**.
 

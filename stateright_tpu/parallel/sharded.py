@@ -465,24 +465,13 @@ class ShardedXlaChecker(Checker):
     def _shard_map(self, fn, in_specs, out_specs):
         import jax
 
-        if hasattr(jax, "shard_map"):  # jax >= 0.8
-            smap = jax.shard_map(
-                fn,
-                mesh=self._mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                check_vma=False,
-            )
-        else:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
-
-            smap = shard_map(
-                fn,
-                mesh=self._mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                check_rep=False,
-            )
+        smap = jax.shard_map(
+            fn,
+            mesh=self._mesh,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            check_vma=False,
+        )
         return jax.jit(smap)
 
     @staticmethod
@@ -1024,8 +1013,7 @@ class ShardedXlaChecker(Checker):
                     # growing for confirmed properties and rows past
                     # hv_cap are dropped silently — harmless only while
                     # _confirm_hv_candidates skips confirmed props, a
-                    # coupling no future consumer should inherit
-                    # (ADVICE r4).
+                    # coupling no future consumer should inherit.
                     lc = lc * jnp.stack(
                         [(~host_found[i]).astype(lc.dtype) for i in hv_idx]
                     )[:, None]

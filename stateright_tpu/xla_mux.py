@@ -1,6 +1,6 @@
 """Multiplexed superstep: K independent jobs in ONE device program.
 
-The roofline (BASELINE.md) says the engine is per-level fixed-cost-bound
+The roofline model (tools/roofline.py) says the engine is per-level fixed-cost-bound
 (~260 ms/level on chip), so under interactive fleet traffic — many *small*
 jobs at rm<=4 — every tenant pays the full sort + dispatch fixed cost
 alone. :class:`MuxChecker` stacks K same-shape-class jobs under one

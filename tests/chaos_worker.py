@@ -8,7 +8,7 @@ marker file, so the supervised RELAUNCH runs clean:
 - ``--die-at-depth N``: SIGKILL itself at the first quiescent point at or
   past depth N (a crash mid-run; nothing gets to flush);
 - ``--freeze-at-depth N``: rewrite the heartbeat to ``phase="dispatch"``
-  and SIGSTOP itself — the exact signature of a wedged tunnel (a frozen
+  and SIGSTOP itself — the exact signature of a hung dispatch (a frozen
   process mid-device-call), which the supervisor must detect by heartbeat
   staleness and kill.
 
@@ -103,7 +103,7 @@ def main() -> int:
             and depth >= args.freeze_at_depth
         ):
             trip()
-            # A wedged tunnel's signature: the engine entered a device
+            # A hung dispatch's signature: the engine entered a device
             # dispatch (heartbeat phase="dispatch", no compile in flight)
             # and never came back.
             if checker._heartbeat is not None:

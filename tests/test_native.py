@@ -17,6 +17,17 @@ def test_native_builds_when_toolchain_present():
     assert native.available()
 
 
+def test_library_is_named_by_source_hash():
+    """Only a library built from the committed source can load: a stale
+    or foreign ``.so`` beside it has another name."""
+    import hashlib
+    import os
+
+    with open(os.path.join(os.path.dirname(native.__file__), "hostkit.cpp"), "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    assert os.path.basename(native._lib_path()) == f"libhostkit-{digest}.so"
+
+
 def test_fingerprint_parity_with_python():
     rng = np.random.default_rng(11)
     for w in (1, 2, 3, 8):

@@ -6,7 +6,7 @@ keep-K rotation, quiescent-boundary-only sampling), and the
 zero-overhead guarantee with tracing/recording off.
 
 These are SCHEMA pins: consumers (tools/roofline.py --measured, the
-bench watchdog, tools/tpu_watch.sh, Perfetto, obs/promexport.py, the
+bench watchdog, supervise.py, Perfetto, obs/promexport.py, the
 ``/.dash`` dashboard) parse these artifacts, so a key rename here is a
 breaking change, not a refactor.
 """

@@ -631,6 +631,7 @@ def test_fleet_replay_folds_routes_and_migrations():
         "device": 1, "pool_job": "job-0002", "spec": "2pc:3",
         "idempotency_key": "k1", "trace_id": None,
         "tenant": "t9", "priority": "interactive", "deadline_s": 120.0,
+        "symmetry": None,
     }
     assert state["routes"]["fjob-0002"]["device"] == 1
     # A pre-QoS record (no tenant/priority) folds to the defaults.
@@ -902,7 +903,8 @@ def test_fleet_pools_export_chaos_to_workers(tmp_path):
         assert live is not None and live.spec == spec
         assert all(p._cfg.chaos == spec for p in fleet.pools)
         env = fleet.pools[0]._worker_env(
-            types.SimpleNamespace(trace_path="unused"), device=False
+            types.SimpleNamespace(trace_path="unused", symmetry=None),
+            device=False,
         )
         assert env["STPU_CHAOS"] == spec
     finally:

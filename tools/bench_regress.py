@@ -41,9 +41,7 @@ also accepts a raw primary-line JSON file (``--fresh line.json``).
 ``--self-test`` proves the gate's three verdicts against the real
 archived trajectory (pass on the newest real line, fail on a synthetically
 degraded copy, no_baseline on an empty dir) — the smoke-stage form, no
-jax, <5 s. ``tools/tpu_watch.sh`` exposes the bare stage alias
-``bench_regress`` so the next chip window self-judges right after its
-bench stage. Exit codes: 0 pass/no_baseline/self-test-ok, 1 fail,
+jax, <5 s. Exit codes: 0 pass/no_baseline/self-test-ok, 1 fail,
 2 tool error (unreadable fresh line).
 """
 

@@ -45,13 +45,9 @@ def main() -> None:
         sys.argv.remove("--cpu")
         jax.config.update("jax_platforms", "cpu")
     else:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                ".jax_cache",
-            ),
-        )
+        from stateright_tpu.backend import configure_compile_cache
+
+        configure_compile_cache()
     import jax.numpy as jnp
 
     from stateright_tpu.ops import deltaset

@@ -38,11 +38,9 @@ def main() -> None:
     if "--cpu" in args:
         jax.config.update("jax_platforms", "cpu")
     else:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+        from stateright_tpu.backend import configure_compile_cache
+
+        configure_compile_cache()
     target = 30_000
     limits = [512, 4_096, 20_000]
     if "--target" in args:

@@ -26,7 +26,8 @@ import jax
 if {cpu!r} == "cpu":
     jax.config.update("jax_platforms", "cpu")
 else:
-    jax.config.update("jax_compilation_cache_dir", {repo!r} + "/.jax_cache")
+    from stateright_tpu.backend import configure_compile_cache
+    configure_compile_cache()
 if os.environ.get("STPU_SORTEDSET_KEYS") == "packed":
     jax.config.update("jax_enable_x64", True)
 from stateright_tpu.models.two_phase_commit import PackedTwoPhaseSys

@@ -72,13 +72,9 @@ def main() -> None:
         sys.argv.remove("--cpu")
         jax.config.update("jax_platforms", "cpu")
     else:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                ".jax_cache",
-            ),
-        )
+        from stateright_tpu.backend import configure_compile_cache
+
+        configure_compile_cache()
     import jax.numpy as jnp
 
     interpret = jax.default_backend() == "cpu"
@@ -131,9 +127,9 @@ def main() -> None:
             )
 
     # --- the engine shape: M=2^24 grid lanes, cap=2^22 (out in HBM) -----
-    # B=512 matches the engine's STPU_PALLAS_BLOCK default (the B=1024
-    # sel+tri operands crowd VMEM — see the xla.py comment).
-    log2_m, B = 24, 512
+    # B=1024 matches the engine's STPU_PALLAS_BLOCK default (the TPU
+    # compiler refuses B=512 — see the xla.py comment).
+    log2_m, B = 24, 1024
     M, cap = 1 << log2_m, 1 << 22
     mask_np = rng.integers(0, 8, M) == 0
     planes_np = rng.integers(0, 2**32, (P, M), dtype=np.uint32)
