@@ -36,10 +36,6 @@ ratio + reduced-run audit in the detail's ``sym`` dict — knob
 ``BENCH_SYM_SPEC``, docs/symmetry.md). With ``STPU_TRACE`` set the workers additionally
 emit the span JSONL (``tools/roofline.py --measured`` consumes it); the
 trace and heartbeat paths are recorded in ``runs/bench_detail.json``.
-Adding ``STPU_PHASES=1`` turns on the dispatch-phase profiler: the
-measured pass's host_prep/enqueue/device_compute/readback split lands in
-the detail's ``phases`` dict (``tools/roofline.py --phases`` is the full
-report; docs/observability.md "Distributed tracing").
 """
 
 from __future__ import annotations
@@ -110,31 +106,6 @@ def _audit(checker) -> dict:
         return audit_table(checker)
     except Exception as e:  # pragma: no cover - diagnostic path
         return {"error": f"{type(e).__name__}: {e}"}
-
-
-def _phase_summary(rows) -> dict | None:
-    """Folds the checker's ``phase_log`` (the dispatch-phase profiler,
-    STPU_PHASES=1) into the bench_detail ``phases`` provenance dict:
-    steady-state per-phase seconds, host-RTT share, device occupancy,
-    and the projected pipelined wall — the same numbers
-    ``tools/roofline.py --phases`` reports from the span trace. None
-    when the profiler was off (no rows)."""
-    if not rows:
-        return None
-    names = ("host_prep", "enqueue", "device_compute", "readback")
-    steady = [r for r in rows if not r.get("compile")]
-    tot = {k: round(sum(r[k] for r in steady), 4) for k in names}
-    host = tot["host_prep"] + tot["enqueue"] + tot["readback"]
-    dev = tot["device_compute"]
-    total = host + dev
-    return {
-        "dispatches": len(rows),
-        "steady_dispatches": len(steady),
-        "steady": tot,
-        "host_share": round(host / max(total, 1e-12), 3),
-        "device_occupancy": round(dev / max(total, 1e-12), 3),
-        "projected_pipelined_sec": round(max(host, dev), 4),
-    }
 
 
 #: This bench process's start, for concurrency checks against artifacts
@@ -750,12 +721,6 @@ def _worker(platform: str) -> None:
         else None
     )
 
-    # Dispatch-phase provenance (tools/roofline.py --phases): when the
-    # profiler ran (STPU_PHASES=1, needs STPU_TRACE), the measured
-    # pass's per-call host/enqueue/device/readback split summarizes
-    # here, so a banked row carries the pipelining-attack numbers.
-    phase_summary = _phase_summary(getattr(checker, "phase_log", None))
-
     sym_info = None
 
     def write_detail(matrix):
@@ -779,9 +744,6 @@ def _worker(platform: str) -> None:
                     "cand_ladder": checker._cand_ladder_k,
                     "cand_retries": checker.cand_retries,
                     "lane_words_per_level": lane_summary,
-                    # Dispatch-phase split (STPU_PHASES=1; None when the
-                    # profiler was off).
-                    "phases": phase_summary,
                     # Resume provenance: which checkpoint (if any) this
                     # worker resumed from, which pass it belonged to, and
                     # the attempt index the parent stamped. levels_replayed

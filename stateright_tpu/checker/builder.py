@@ -108,11 +108,10 @@ class CheckerBuilder:
         device dispatch so watchdogs can tell a hung dispatch from a
         long XLA compile (env ``STPU_HEARTBEAT``). Both off by default;
         neither adds device syncs. ``checker.metrics()`` returns the
-        unified counters/gauges snapshot either way. ``phases=True``
-        (env ``STPU_PHASES=1``, needs a live tracer) turns on the
-        dispatch-phase profiler: each device call splits into
-        host_prep/enqueue/device_compute/readback sub-spans plus a
-        ``checker.phase_log`` row (``tools/roofline.py --phases``).
+        unified counters/gauges snapshot either way. Every span is also
+        a ``jax.profiler`` annotation, so inside a profiler session the
+        engine's spans sit on the device trace's clock; the first call
+        installs ``obs.compile_log``, JAX's compile durations.
 
         With ``mesh`` (a ``jax.sharding.Mesh`` with one axis, more than one
         device), the frontier and visited set shard by fingerprint ownership
@@ -126,6 +125,9 @@ class CheckerBuilder:
             raise NotImplementedError(
                 "spawn_xla() is not available yet in this build"
             ) from e
+        from .. import obs
+
+        obs.install_compile_log()
         if mesh is not None and mesh.devices.size > 1:
             from ..parallel import ShardedXlaChecker
 

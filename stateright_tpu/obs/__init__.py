@@ -34,10 +34,17 @@ and *liveness*:
   ``GET /.metrics``; ships the validating parser the tests and smoke
   stage scrape with.
 
+- :mod:`~stateright_tpu.obs.compiles` — JAX's tracing, lowering,
+  cache-load and compile durations with their end times
+  (``compile_log``), for readers with a time bound such as a
+  benchmark's set-up.
+
 Everything here is OFF by default and adds **no device syncs** when on:
 spans only wrap host boundaries and reuse scalars the host already fetches.
 With tracing off the engines hold the shared :data:`NULL_TRACER`, whose
-``span()`` returns a no-op context — no files, no clocks, no allocation.
+``span()`` opens only a ``jax.profiler`` annotation — no files, no clocks;
+the annotation records only inside a profiler session, where the engine's
+spans then sit on the device trace's clock.
 
 Schemas are documented in ``docs/observability.md`` and pinned by
 ``tests/test_obs.py``.
@@ -49,6 +56,8 @@ import os
 from typing import Optional, Union
 
 from . import collect
+from .compiles import LOG as compile_log
+from .compiles import install as install_compile_log
 from .heartbeat import Heartbeat
 from .metrics import Counters
 from .timeseries import MetricsRecorder, read_series
@@ -72,8 +81,10 @@ __all__ = [
     "Span",
     "Tracer",
     "collect",
+    "compile_log",
     "export_chrome",
     "format_ctx",
+    "install_compile_log",
     "new_trace_id",
     "parse_ctx",
     "read_series",

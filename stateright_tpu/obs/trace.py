@@ -30,13 +30,14 @@ several processes can be aligned. Span names the engines emit:
 
 ``dispatch``
     One host→device→host round-trip of a compiled superstep program (one
-    or many BFS levels). Attrs: ``flavor`` (``fused``/``single``),
-    ``bucket`` (run rows), ``cand`` (candidate cap, or the ladder's rung
-    list under fused dispatch), ``committed`` (levels committed — 0 means
-    an overflow exit), ``compile`` (this call traced+compiled a fresh XLA
-    program: its wall-clock includes the compile), ``retry`` (re-run of a
-    level after an overflow recovery), ``dedup``, ``compaction``, and —
-    fused path — ``shrink_below`` when a shrink-exit threshold is armed.
+    or many BFS levels), with the host work that commits it. Attrs:
+    ``flavor`` (``fused``/``single``), ``bucket`` (run rows), ``cand``
+    (candidate cap, or the ladder's rung list under fused dispatch),
+    ``committed`` (levels committed — 0 means an overflow exit),
+    ``compile`` (this call traced+compiled a fresh XLA program: its
+    wall-clock includes the compile), ``retry`` (re-run of a level after
+    an overflow recovery), ``dedup``, ``compaction``, and — fused path —
+    ``shrink_below`` when a shrink-exit threshold is armed.
 ``grow_table``
     Visited-set growth (rehash / plane copy) — the overflow-recovery
     device work. Attrs: ``dedup``, ``capacity`` (new).
@@ -47,16 +48,25 @@ several processes can be aligned. Span names the engines emit:
 ``host_verify``
     Host-side exact re-check of device-flagged candidates for
     host-verified properties. Attrs: ``checked``, ``confirmed``.
-``phase:host_prep`` / ``phase:enqueue`` / ``phase:device_compute`` /
-``phase:readback``
-    The dispatch-phase profiler's sub-spans (``spawn_xla(phases=True)`` /
-    ``STPU_PHASES=1``, off by default): contiguous sub-intervals of ONE
-    parent ``dispatch`` span (``parent_id`` = the dispatch span's
-    ``span_id``), splitting the host→device round-trip into input
-    staging, the async program enqueue (compile rides here on a fresh
-    program), the ``block_until_ready`` wait, and the host-side scalar
-    readback. Attrs: ``bucket``. Consumed by ``tools/roofline.py
-    --phases``.
+``spawn``
+    ``XlaChecker.__init__``: configuration, the visited set, the init
+    insert and the first frontier. Inside it, ``spawn:table`` (the empty
+    visited set), ``spawn:init_insert`` (init-state fingerprints into the
+    set, read back) and ``spawn:frontier`` (the padded first frontier).
+``dispatch:prepare`` / ``dispatch:run`` / ``dispatch:commit``
+    The three consecutive parts of one ``dispatch`` span (single-chip
+    engine): the bucketed inputs, program lookup and arguments; the call
+    through the blocking read of its committed count; the telemetry
+    readback and host bookkeeping up to the next dispatch (the
+    ``host_verify``, ``grow_table``, ``delta_flush`` and ``checkpoint``
+    spans of that step nest inside it).
+
+Every span, the tracer's and the off tracer's alike, also opens a
+``jax.profiler.TraceAnnotation`` of its name, so the engine's spans land
+on the host plane of a ``jax.profiler`` trace, on the device events'
+clock. The annotation records only while a profiler session runs, costs
+under a microsecond otherwise, and is skipped in a process that has not
+imported jax (no session can run there).
 
 The exporter (:func:`export_chrome`) rewrites a span JSONL as one Chrome
 trace-event JSON object (``{"traceEvents": [...]}``, complete events,
@@ -71,6 +81,7 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
 import time
 from typing import Any, Dict, Optional, Tuple
 
@@ -104,13 +115,43 @@ def parse_ctx(value: Optional[str]) -> Optional[Tuple[str, Optional[str]]]:
     return trace_id, (parent or None)
 
 
+class _NoAnnotation:
+    """Stands in for the profiler annotation where jax is not imported."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        pass
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NO_ANNOTATION = _NoAnnotation()
+_TraceAnnotation = None
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``: a host event in
+    a running profiler session, next to nothing otherwise. Imports
+    nothing: where jax is not imported yet, no session can run."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        if sys.modules.get("jax") is None:
+            return _NO_ANNOTATION
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
+
+
 class Span:
     """Context manager recording one wall-clock span; attributes may be
     added mid-span with :meth:`set` (e.g. counts only known after the
     host syncs the dispatch results). ``span_id`` is allocated at entry so
-    in-flight consumers (the phase profiler) can parent sub-spans to it."""
+    in-flight consumers can parent sub-spans to it."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "span_id")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "span_id", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -121,38 +162,41 @@ class Span:
         self.attrs.update(attrs)
 
     def __enter__(self) -> "Span":
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self.span_id = self._tracer._new_sid()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> bool:
         t0 = self._t0
-        self._tracer._emit(
-            self.name, t0, time.monotonic() - t0, self.attrs,
-            span_id=self.span_id,
-        )
+        dur = time.monotonic() - t0
+        self._ann.__exit__(*exc)
+        self._tracer._emit(self.name, t0, dur, self.attrs, span_id=self.span_id)
         return False
 
 
 class _NullSpan:
-    """The do-nothing span: tracing off costs two attribute lookups and a
-    shared-singleton return — no clock reads, no allocation, no I/O."""
+    """The span of the off tracer: only the profiler annotation — no
+    clock read, no record, no I/O."""
 
-    __slots__ = ()
+    __slots__ = ("_ann",)
 
     span_id = None
+
+    def __init__(self, name: str):
+        self._ann = _annotation(name)
 
     def set(self, **attrs: Any) -> None:
         pass
 
     def __enter__(self) -> "_NullSpan":
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
         return False
-
-
-_NULL_SPAN = _NullSpan()
 
 
 class _NullTracer:
@@ -160,7 +204,7 @@ class _NullTracer:
     trace_id = None
 
     def span(self, name: str, **attrs: Any) -> _NullSpan:
-        return _NULL_SPAN
+        return _NullSpan(name)
 
     def emit(self, name: str, **kw: Any) -> Optional[str]:
         return None
@@ -240,8 +284,8 @@ class Tracer:
              trace_id: Optional[str] = None,
              span_id: Optional[str] = None) -> Optional[str]:
         """Emit one pre-timed span (``t0`` on the ``time.monotonic`` clock,
-        ``dur`` seconds) and return its span_id. The phase profiler and
-        the service tiers use this for intervals measured with raw stamps
+        ``dur`` seconds) and return its span_id. The service tiers use
+        this for intervals measured with raw stamps
         rather than a ``with`` block. ``trace_id`` overrides the tracer's
         ambient context per record — a SHARED tracer (one service file,
         many concurrent jobs) must not mutate ambient state per job — and

@@ -294,7 +294,6 @@ class XlaChecker(Checker):
         metrics_to: Any = None,
         metrics_every: Any = None,
         metrics_keep: Optional[int] = None,
-        phases: Any = None,
     ):
         import jax
 
@@ -309,473 +308,455 @@ class XlaChecker(Checker):
         # boundaries), and the event-counter half of metrics(). All
         # host-side; never a device sync.
         self._tracer = obs.resolve_tracer(trace)
-        self._heartbeat = obs.resolve_heartbeat(heartbeat)
-        self._recorder = obs.resolve_recorder(
-            metrics_to, metrics_every, metrics_keep
-        )
-        self._counters = obs.Counters(ENGINE_COUNTERS)
-        # Dispatch-phase profiler (docs/observability.md "Distributed
-        # tracing"): split every device call into host_prep / enqueue /
-        # device_compute / readback sub-spans. The split needs ONE extra
-        # host-side wait (block_until_ready on work already enqueued —
-        # never a new device sync beyond the commit read the loop pays
-        # anyway), so it is off by default and requires a live tracer;
-        # the off path is byte-identical to pre-profiler dispatch.
-        if phases is None:
-            phases = os.environ.get("STPU_PHASES") or None
-        if isinstance(phases, str):
-            low = phases.strip().lower()
-            if low in ("1", "on", "true", "yes"):
-                phases = True
-            elif low in ("0", "off", "false", "no", ""):
-                phases = False
-            else:
-                raise ValueError(
-                    f"phases must be on/off (STPU_PHASES), got {phases!r}"
+        # One span over the set-up below: the host-side configuration,
+        # the empty visited set, the init insert and the first frontier.
+        with self._tracer.span("spawn"):
+            self._heartbeat = obs.resolve_heartbeat(heartbeat)
+            self._recorder = obs.resolve_recorder(
+                metrics_to, metrics_every, metrics_keep
+            )
+            self._counters = obs.Counters(ENGINE_COUNTERS)
+            # Recovery surface (stateright_tpu/checkpoint.py): in-loop
+            # auto-checkpointing at superstep boundaries (the quiescent
+            # points), plus the resume-provenance gauges metrics() reports.
+            from .checkpoint import AutoCheckpointer
+
+            self._autockpt = AutoCheckpointer.resolve(
+                checkpoint_to, checkpoint_every, checkpoint_keep
+            )
+            self._last_checkpoint: Optional[Dict[str, Any]] = None
+            self._resumed_from: Optional[str] = checkpoint
+            # Symmetry reduction (stateright_tpu/sym, docs/symmetry.md):
+            # resolve the spawn_xla(symmetry=) / STPU_SYMMETRY knob against
+            # the builder request and the model's capability. When on, the
+            # frontier canonicalizes through either the spec-compiled
+            # scatter-free kernel (tag "spec:<hash>") or the model's
+            # hand-written packed_representative; unsupported paths raise
+            # SymmetryUnsupported instead of silently exploring full-space.
+            from .sym import SymmetryUnsupported, resolve_symmetry
+
+            _sym = resolve_symmetry(
+                symmetry, builder._symmetry is not None, model, engine="xla"
+            )
+            self._symmetry = _sym.enabled
+            self._sym_tag = _sym.tag
+            self._sym_canon = _sym.device_canon
+            self._sym_canon_host = _sym.host_canon
+            if self._symmetry and getattr(model, "host_verified_properties", ()):
+                # The hv fallback re-runs exact host predicates on CONCRETE
+                # candidate states; a symmetry-reduced frontier only surfaces
+                # one member per class, so an asymmetric hv property could
+                # silently miss its witness. Typed refusal, not silent
+                # wrongness.
+                raise SymmetryUnsupported(
+                    "xla",
+                    f"{type(model).__name__} declares host_verified_properties; "
+                    f"the host-verified fallback evaluates concrete states and "
+                    f"cannot honor a symmetry-reduced frontier",
                 )
-        self._phases = bool(phases) and self._tracer.enabled
-        #: One dict per device call (aligned with ``dispatch_log``):
-        #: bucket/flavor/compile/committed + the four phase durations in
-        #: seconds. Populated only when the profiler is on.
-        self.phase_log: List[Dict[str, Any]] = []
-        # Recovery surface (stateright_tpu/checkpoint.py): in-loop
-        # auto-checkpointing at superstep boundaries (the quiescent
-        # points), plus the resume-provenance gauges metrics() reports.
-        from .checkpoint import AutoCheckpointer
+            self._target_state_count: Optional[int] = builder._target_state_count
+            self._target_max_depth: Optional[int] = builder._target_max_depth
+            self._visitor = builder._visitor
+            self._properties = model.properties()
+            self._prop_names = [p.name for p in self._properties]
+            # Eventually-property bit assignment: position among the eventually
+            # subset (checker.rs:540-547).
+            self._ebit_of_prop: Dict[int, int] = {}
+            for i, p in enumerate(self._properties):
+                if p.expectation == Expectation.EVENTUALLY:
+                    self._ebit_of_prop[i] = len(self._ebit_of_prop)
+            self._ebits0 = (1 << len(self._ebit_of_prop)) - 1
 
-        self._autockpt = AutoCheckpointer.resolve(
-            checkpoint_to, checkpoint_every, checkpoint_keep
-        )
-        self._last_checkpoint: Optional[Dict[str, Any]] = None
-        self._resumed_from: Optional[str] = checkpoint
-        # Symmetry reduction (stateright_tpu/sym, docs/symmetry.md):
-        # resolve the spawn_xla(symmetry=) / STPU_SYMMETRY knob against
-        # the builder request and the model's capability. When on, the
-        # frontier canonicalizes through either the spec-compiled
-        # scatter-free kernel (tag "spec:<hash>") or the model's
-        # hand-written packed_representative; unsupported paths raise
-        # SymmetryUnsupported instead of silently exploring full-space.
-        from .sym import SymmetryUnsupported, resolve_symmetry
-
-        _sym = resolve_symmetry(
-            symmetry, builder._symmetry is not None, model, engine="xla"
-        )
-        self._symmetry = _sym.enabled
-        self._sym_tag = _sym.tag
-        self._sym_canon = _sym.device_canon
-        self._sym_canon_host = _sym.host_canon
-        if self._symmetry and getattr(model, "host_verified_properties", ()):
-            # The hv fallback re-runs exact host predicates on CONCRETE
-            # candidate states; a symmetry-reduced frontier only surfaces
-            # one member per class, so an asymmetric hv property could
-            # silently miss its witness. Typed refusal, not silent
-            # wrongness (ISSUE 19 satellite).
-            raise SymmetryUnsupported(
-                "xla",
-                f"{type(model).__name__} declares host_verified_properties; "
-                f"the host-verified fallback evaluates concrete states and "
-                f"cannot honor a symmetry-reduced frontier",
+            # Visited-set structure. The cost model measured on an earlier chip setup (to be re-measured, ROADMAP S3) showed
+            # the scatter-election hash insert is the TPU bottleneck (0.24 M
+            # ins/s at 2^22) while sort runs at ~1.3 G keys/s, and that stream
+            # compaction inverts the same way (gather 3x over scatter) — so
+            # accelerators default to the sort-merge set + gather compaction
+            # (ops/sortedset.py) and CPUs keep the hash set + scatter compaction
+            # that wins there.
+            # A planes-only compaction request (explicit arg or the
+            # STPU_COMPACTION env A/B knob behind "auto") re-aims the dedup
+            # auto: "bsearch"/"pallas" exist only in the plane-major engine,
+            # and resolving dedup to hash-on-CPU first would reject the
+            # combination the caller asked for (the r5e watcher's CPU
+            # fallback died exactly there).
+            requested_compaction = (
+                os.environ.get("STPU_COMPACTION") or "auto"
+                if compaction == "auto"
+                else compaction
             )
-        self._target_state_count: Optional[int] = builder._target_state_count
-        self._target_max_depth: Optional[int] = builder._target_max_depth
-        self._visitor = builder._visitor
-        self._properties = model.properties()
-        self._prop_names = [p.name for p in self._properties]
-        # Eventually-property bit assignment: position among the eventually
-        # subset (checker.rs:540-547).
-        self._ebit_of_prop: Dict[int, int] = {}
-        for i, p in enumerate(self._properties):
-            if p.expectation == Expectation.EVENTUALLY:
-                self._ebit_of_prop[i] = len(self._ebit_of_prop)
-        self._ebits0 = (1 << len(self._ebit_of_prop)) - 1
-
-        # Visited-set structure. The cost model measured on an earlier chip setup (to be re-measured, ROADMAP S3) showed
-        # the scatter-election hash insert is the TPU bottleneck (0.24 M
-        # ins/s at 2^22) while sort runs at ~1.3 G keys/s, and that stream
-        # compaction inverts the same way (gather 3x over scatter) — so
-        # accelerators default to the sort-merge set + gather compaction
-        # (ops/sortedset.py) and CPUs keep the hash set + scatter compaction
-        # that wins there.
-        # A planes-only compaction request (explicit arg or the
-        # STPU_COMPACTION env A/B knob behind "auto") re-aims the dedup
-        # auto: "bsearch"/"pallas" exist only in the plane-major engine,
-        # and resolving dedup to hash-on-CPU first would reject the
-        # combination the caller asked for (the r5e watcher's CPU
-        # fallback died exactly there).
-        requested_compaction = (
-            os.environ.get("STPU_COMPACTION") or "auto"
-            if compaction == "auto"
-            else compaction
-        )
-        if dedup == "auto":
-            dedup = (
-                "sorted"
-                if requested_compaction in ("bsearch", "pallas")
-                else auto_dedup(jax.default_backend())
-            )
-        if dedup not in ("hash", "sorted", "delta"):
-            raise ValueError(
-                f"dedup must be 'auto', 'hash', 'sorted', or 'delta': {dedup!r}"
-            )
-        self._dedup = dedup
-        self._ds = {"hash": hashset, "sorted": sortedset, "delta": deltaset}[dedup]
-        # Structure-of-arrays state layout rides with the sorted (accelerator)
-        # structure: XLA:TPU tiles the minor two dims of every buffer to
-        # (8, 128), so a [N, W] row-major frontier with W=2 pads 2 lanes to
-        # 128 — a ~64x memory-traffic blowup on every elementwise op and
-        # gather over packed states. Plane-major [W, N] buffers keep N on
-        # the 128-lane axis. The planes superstep preserves the rows
-        # superstep's semantics bit-for-bit (candidates are restored to
-        # state-major order before the insert's winner election).
-        self._soa = dedup != "hash"
-        # Planes-compaction lowering: "gather" computes the permutation
-        # once (one small sort) and gathers every plane by it; "sort"
-        # carries the planes as sort payload operands — no random gathers,
-        # more sorted bytes; "bsearch" replaces the permutation sort with
-        # cumsum + rank binary-search + ascending gathers. The round-5
-        # on-chip A/Bs settled the hardware question per shape class:
-        #   - narrow-W (2pc W=2, rm=8): sort 8.8s vs gather 15.6s vs
-        #     bsearch 29.0s measured — random gathers at table scale are
-        #     the dominant per-level cost and sort payload wins;
-        #   - wide-W (paxos W=25): the sort-mode grid compaction becomes a
-        #     W+3 = 28-operand lax.sort whose XLA:TPU *compile* stalls for
-        #     tens of minutes (two bench workers in a row), while gather
-        #     compiles in ~2 min and measures fastest (3.2s vs bsearch
-        #     4.6s);
-        #   - 1-core CPU: gather wins everywhere (round-3 model).
-        # So "auto" resolves per backend AND per model width: sort-family
-        # compaction only where its operand count stays small.
-        # STPU_COMPACTION still makes the A/B a process restart.
-        if compaction == "auto":
-            compaction = os.environ.get("STPU_COMPACTION") or (
-                "gather"
-                if jax.default_backend() == "cpu"
-                else accel_auto_compaction(model.state_words)
-            )
-        # "pallas": the state-major layout of "bsearch" with the
-        # compaction itself as a sequential-grid pallas streaming kernel
-        # (ops/pallas_compact.py) — O(n) data movement instead of the
-        # sort's O(n log^2 n). Opt-in until chip-proven; small shapes
-        # (bucket below the kernel block) fall back to the stable sort
-        # inside compact_1d, bit-identically.
-        if compaction not in ("gather", "sort", "bsearch", "pallas"):
-            raise ValueError(
-                "compaction must be 'auto', 'gather', 'sort', "
-                f"'bsearch', or 'pallas': {compaction!r}"
-            )
-        if compaction in ("bsearch", "pallas") and not self._soa:
-            # (bsearch included: the rows superstep never consults the
-            # compaction knob, and silently measuring the hash engine
-            # under an STPU_COMPACTION=bsearch A/B would mislabel the
-            # banked numbers.)
-            raise ValueError(
-                f"compaction={compaction!r} runs in the plane-major "
-                "engine: pass dedup='sorted' or 'delta' (the hash "
-                "engine is the rows path)"
-            )
-        self._compaction = compaction
-        # Bucket-ladder policy. "ramp" steps one power-of-four rung per
-        # frontier overflow — for a space that widens to 2^19 that is 8
-        # separate XLA compiles of the full superstep program, and compile
-        # time is large (~10 s each on 1-core CPU; tens of seconds to
-        # minutes from the TPU compiler, growing with the sort sizes), so
-        # the ramp IS the warm-pass cost for ramping spaces (paxos warm
-        # 47 s, 4 buckets, on CPU). "jump" extrapolates the observed level
-        # growth to skip rungs (see _grow_frontier) and prefers an
-        # already-compiled bucket over compiling a snug one
-        # (_run_cap_for); padding a level costs milliseconds, a fresh
-        # compile costs far more. The "jump" default was measured on an
-        # earlier chip setup and is to be re-measured (ROADMAP S5). Counts
-        # are bucket-independent; STPU_LADDER makes the A/B a process
-        # restart.
-        if ladder == "auto":
-            ladder = os.environ.get("STPU_LADDER", "jump")
-        if ladder not in ("jump", "ramp"):
-            raise ValueError(f"ladder must be 'auto', 'jump', or 'ramp': {ladder!r}")
-        self._ladder = ladder
-        # Tail shrink-exit policy. The downshift is a pure host-side
-        # dispatch decision — the threshold rides into the compiled
-        # program as a runtime scalar — so this knob never costs a
-        # compile. "auto": on for CPU, off for accelerators. Each tail
-        # downshift is an extra host round-trip, and on an earlier chip
-        # setup, whose round-trips were slow, the rm=8 A/B measured the
-        # ~7 tail round-trips at ~1.1 s against ~0.15 s of grid-sort
-        # savings (2.13 M -> 1.88 M gen/s, same schedule, same counts);
-        # on 1-core CPU dispatch is ~free and the snug tail sorts won
-        # (rm=6 ramp tail 16384 -> 4096 -> 1024 -> 256). The "off"
-        # default on accelerators is to be re-measured on the driver's
-        # chip (ROADMAP S5), where round-trips may be far cheaper.
-        # STPU_SHRINK_EXIT makes the A/B a process restart.
-        if shrink_exit == "auto":
-            shrink_exit = os.environ.get("STPU_SHRINK_EXIT") or (
-                "on" if jax.default_backend() == "cpu" else "off"
-            )
-        if shrink_exit not in ("on", "off"):
-            raise ValueError(
-                f"shrink_exit must be 'auto', 'on', or 'off': {shrink_exit!r}"
-            )
-        self._shrink_exit = shrink_exit == "on"
-        # In-program candidate-width ladder (delivered IN-PROGRAM per the
-        # shrink-exit lesson: on the earlier chip setup any scheme that
-        # added host dispatches to the tail paid ~150 ms per round-trip,
-        # so snug candidate sorts ride inside the fused
-        # ``lax.while_loop``). Fused dispatches
-        # branch via ``lax.switch`` over up to K sub-width supersteps —
-        # each rung is the (frontier rows, candidate cap) shape a smaller
-        # host bucket would run, specialised into the peak program — so a
-        # narrow level's candidate-scale sorts (the [table ‖ cand] insert
-        # merge, the frontier compaction) and its grid-scale compaction
-        # all run snug with ZERO added host round-trips. Branch selection
-        # is on-device (see _build_fused); an underestimate falls through
-        # to the full-width branch in-program, never dropping candidates.
-        # "auto" = STPU_CAND_LADDER or 3 (on for CPU and accelerators —
-        # the savings are in-program, so there is no RTT trade); 1
-        # disables (one branch = the pre-ladder program, byte-for-byte).
-        # Each rung is a full superstep trace, so K bounds the fused
-        # program's compile cost (~11 s/bucket baseline on 1-core CPU,
-        # measured on CPU). Planes engine only: the rows/hash superstep
-        # has no candidate-scale sorts to snug.
-        explicit_cand_ladder = cand_ladder != "auto"
-        env_cand_ladder = bool(os.environ.get("STPU_CAND_LADDER"))
-        if cand_ladder == "auto":
-            cand_ladder = os.environ.get("STPU_CAND_LADDER") or str(
-                CAND_LADDER_AUTO_K
-            )
-        try:
-            ladder_k = int(cand_ladder)
-        except (TypeError, ValueError):
-            raise ValueError(
-                f"cand_ladder must be 'auto' or an int in 1..3: {cand_ladder!r}"
-            ) from None
-        if not 1 <= ladder_k <= 3:
-            raise ValueError(f"cand_ladder must be in 1..3: {ladder_k}")
-        if ladder_k > 1 and not self._soa:
-            if explicit_cand_ladder:
-                raise ValueError(
-                    "cand_ladder runs in the plane-major engine: pass "
-                    "dedup='sorted' or 'delta' (the hash engine's rows "
-                    "superstep has no candidate-scale sorts to snug)"
+            if dedup == "auto":
+                dedup = (
+                    "sorted"
+                    if requested_compaction in ("bsearch", "pallas")
+                    else auto_dedup(jax.default_backend())
                 )
-            if env_cand_ladder:
-                # Only an explicit env A/B request warns; the default
-                # auto→3 resolving to 1 on the hash engine is the normal
-                # CPU configuration, not a misconfiguration.
+            if dedup not in ("hash", "sorted", "delta"):
+                raise ValueError(
+                    f"dedup must be 'auto', 'hash', 'sorted', or 'delta': {dedup!r}"
+                )
+            self._dedup = dedup
+            self._ds = {"hash": hashset, "sorted": sortedset, "delta": deltaset}[dedup]
+            # Structure-of-arrays state layout rides with the sorted (accelerator)
+            # structure: XLA:TPU tiles the minor two dims of every buffer to
+            # (8, 128), so a [N, W] row-major frontier with W=2 pads 2 lanes to
+            # 128 — a ~64x memory-traffic blowup on every elementwise op and
+            # gather over packed states. Plane-major [W, N] buffers keep N on
+            # the 128-lane axis. The planes superstep preserves the rows
+            # superstep's semantics bit-for-bit (candidates are restored to
+            # state-major order before the insert's winner election).
+            self._soa = dedup != "hash"
+            # Planes-compaction lowering: "gather" computes the permutation
+            # once (one small sort) and gathers every plane by it; "sort"
+            # carries the planes as sort payload operands — no random gathers,
+            # more sorted bytes; "bsearch" replaces the permutation sort with
+            # cumsum + rank binary-search + ascending gathers. The round-5
+            # on-chip A/Bs settled the hardware question per shape class:
+            #   - narrow-W (2pc W=2, rm=8): sort 8.8s vs gather 15.6s vs
+            #     bsearch 29.0s measured — random gathers at table scale are
+            #     the dominant per-level cost and sort payload wins;
+            #   - wide-W (paxos W=25): the sort-mode grid compaction becomes a
+            #     W+3 = 28-operand lax.sort whose XLA:TPU *compile* stalls for
+            #     tens of minutes (two bench workers in a row), while gather
+            #     compiles in ~2 min and measures fastest (3.2s vs bsearch
+            #     4.6s);
+            #   - 1-core CPU: gather wins everywhere (round-3 model).
+            # So "auto" resolves per backend AND per model width: sort-family
+            # compaction only where its operand count stays small.
+            # STPU_COMPACTION still makes the A/B a process restart.
+            if compaction == "auto":
+                compaction = os.environ.get("STPU_COMPACTION") or (
+                    "gather"
+                    if jax.default_backend() == "cpu"
+                    else accel_auto_compaction(model.state_words)
+                )
+            # "pallas": the state-major layout of "bsearch" with the
+            # compaction itself as a sequential-grid pallas streaming kernel
+            # (ops/pallas_compact.py) — O(n) data movement instead of the
+            # sort's O(n log^2 n). Opt-in until chip-proven; small shapes
+            # (bucket below the kernel block) fall back to the stable sort
+            # inside compact_1d, bit-identically.
+            if compaction not in ("gather", "sort", "bsearch", "pallas"):
+                raise ValueError(
+                    "compaction must be 'auto', 'gather', 'sort', "
+                    f"'bsearch', or 'pallas': {compaction!r}"
+                )
+            if compaction in ("bsearch", "pallas") and not self._soa:
+                # (bsearch included: the rows superstep never consults the
+                # compaction knob, and silently measuring the hash engine
+                # under an STPU_COMPACTION=bsearch A/B would mislabel the
+                # banked numbers.)
+                raise ValueError(
+                    f"compaction={compaction!r} runs in the plane-major "
+                    "engine: pass dedup='sorted' or 'delta' (the hash "
+                    "engine is the rows path)"
+                )
+            self._compaction = compaction
+            # Bucket-ladder policy. "ramp" steps one power-of-four rung per
+            # frontier overflow — for a space that widens to 2^19 that is 8
+            # separate XLA compiles of the full superstep program, and compile
+            # time is large (~10 s each on 1-core CPU; tens of seconds to
+            # minutes from the TPU compiler, growing with the sort sizes), so
+            # the ramp IS the warm-pass cost for ramping spaces (paxos warm
+            # 47 s, 4 buckets, on CPU). "jump" extrapolates the observed level
+            # growth to skip rungs (see _grow_frontier) and prefers an
+            # already-compiled bucket over compiling a snug one
+            # (_run_cap_for); padding a level costs milliseconds, a fresh
+            # compile costs far more. The "jump" default was measured on an
+            # earlier chip setup and is to be re-measured (ROADMAP S5). Counts
+            # are bucket-independent; STPU_LADDER makes the A/B a process
+            # restart.
+            if ladder == "auto":
+                ladder = os.environ.get("STPU_LADDER", "jump")
+            if ladder not in ("jump", "ramp"):
+                raise ValueError(f"ladder must be 'auto', 'jump', or 'ramp': {ladder!r}")
+            self._ladder = ladder
+            # Tail shrink-exit policy. The downshift is a pure host-side
+            # dispatch decision — the threshold rides into the compiled
+            # program as a runtime scalar — so this knob never costs a
+            # compile. "auto": on for CPU, off for accelerators. Each tail
+            # downshift is an extra host round-trip, and on an earlier chip
+            # setup, whose round-trips were slow, the rm=8 A/B measured the
+            # ~7 tail round-trips at ~1.1 s against ~0.15 s of grid-sort
+            # savings (2.13 M -> 1.88 M gen/s, same schedule, same counts);
+            # on 1-core CPU dispatch is ~free and the snug tail sorts won
+            # (rm=6 ramp tail 16384 -> 4096 -> 1024 -> 256). The "off"
+            # default on accelerators is to be re-measured on a TPU v5e
+            # (ROADMAP S5), where round-trips may be far cheaper.
+            # STPU_SHRINK_EXIT makes the A/B a process restart.
+            if shrink_exit == "auto":
+                shrink_exit = os.environ.get("STPU_SHRINK_EXIT") or (
+                    "on" if jax.default_backend() == "cpu" else "off"
+                )
+            if shrink_exit not in ("on", "off"):
+                raise ValueError(
+                    f"shrink_exit must be 'auto', 'on', or 'off': {shrink_exit!r}"
+                )
+            self._shrink_exit = shrink_exit == "on"
+            # In-program candidate-width ladder (delivered IN-PROGRAM per the
+            # shrink-exit lesson: on the earlier chip setup any scheme that
+            # added host dispatches to the tail paid ~150 ms per round-trip,
+            # so snug candidate sorts ride inside the fused
+            # ``lax.while_loop``). Fused dispatches
+            # branch via ``lax.switch`` over up to K sub-width supersteps —
+            # each rung is the (frontier rows, candidate cap) shape a smaller
+            # host bucket would run, specialised into the peak program — so a
+            # narrow level's candidate-scale sorts (the [table ‖ cand] insert
+            # merge, the frontier compaction) and its grid-scale compaction
+            # all run snug with ZERO added host round-trips. Branch selection
+            # is on-device (see _build_fused); an underestimate falls through
+            # to the full-width branch in-program, never dropping candidates.
+            # "auto" = STPU_CAND_LADDER or 3 (on for CPU and accelerators —
+            # the savings are in-program, so there is no RTT trade); 1
+            # disables (one branch = the pre-ladder program, byte-for-byte).
+            # Each rung is a full superstep trace, so K bounds the fused
+            # program's compile cost (~11 s/bucket baseline on 1-core CPU,
+            # measured on CPU). Planes engine only: the rows/hash superstep
+            # has no candidate-scale sorts to snug.
+            explicit_cand_ladder = cand_ladder != "auto"
+            env_cand_ladder = bool(os.environ.get("STPU_CAND_LADDER"))
+            if cand_ladder == "auto":
+                cand_ladder = os.environ.get("STPU_CAND_LADDER") or str(
+                    CAND_LADDER_AUTO_K
+                )
+            try:
+                ladder_k = int(cand_ladder)
+            except (TypeError, ValueError):
+                raise ValueError(
+                    f"cand_ladder must be 'auto' or an int in 1..3: {cand_ladder!r}"
+                ) from None
+            if not 1 <= ladder_k <= 3:
+                raise ValueError(f"cand_ladder must be in 1..3: {ladder_k}")
+            if ladder_k > 1 and not self._soa:
+                if explicit_cand_ladder:
+                    raise ValueError(
+                        "cand_ladder runs in the plane-major engine: pass "
+                        "dedup='sorted' or 'delta' (the hash engine's rows "
+                        "superstep has no candidate-scale sorts to snug)"
+                    )
+                if env_cand_ladder:
+                    # Only an explicit env A/B request warns; the default
+                    # auto→3 resolving to 1 on the hash engine is the normal
+                    # CPU configuration, not a misconfiguration.
+                    import warnings
+
+                    warnings.warn(
+                        "STPU_CAND_LADDER has no effect with dedup='hash' "
+                        "(rows-major superstep); the knob applies to the "
+                        "sorted/delta planes engine only",
+                        RuntimeWarning,
+                        stacklevel=3,
+                    )
+                ladder_k = 1
+            self._cand_ladder_k = ladder_k
+            #: In-program fall-throughs (snug branch overflowed, level re-ran
+            #: at full width inside the same dispatch) — the ladder's only
+            #: waste case, observable for tests and the A/B harness.
+            self.cand_retries = 0
+            # Expand-stage layout (an A/B knob
+            # for the chip window). "rows" materializes the [F, A, W] grid the
+            # vmap naturally produces, then transposes to [W, A*F] planes —
+            # the intermediate has W=2 on the minor axis, i.e. the (8,128)
+            # tiling tax on its full traffic. "planes" asks the vmap to emit
+            # [A, W, F] directly (out_axes=2), keeping F minor throughout —
+            # no padded intermediate. NOT default anywhere: a transpose fused
+            # INTO a vmapped kernel is the exact shape XLA:CPU (jax 0.9.0)
+            # miscompiles (_build_superstep_planes docstring), so "planes" is
+            # for accelerator A/Bs guarded by count_ok + the table audit.
+            expand_layout = os.environ.get("STPU_EXPAND_LAYOUT", "rows")
+            if expand_layout not in ("rows", "planes"):
+                raise ValueError(
+                    f"STPU_EXPAND_LAYOUT must be 'rows' or 'planes': {expand_layout!r}"
+                )
+            if expand_layout == "planes" and not self._soa:
+                # The knob only exists in the planes superstep; an A/B run on
+                # the rows-major (hash-dedup) builder would silently measure
+                # two identical programs.
                 import warnings
 
                 warnings.warn(
-                    "STPU_CAND_LADDER has no effect with dedup='hash' "
+                    "STPU_EXPAND_LAYOUT=planes has no effect with dedup='hash' "
                     "(rows-major superstep); the knob applies to the "
                     "sorted/delta planes engine only",
                     RuntimeWarning,
                     stacklevel=3,
                 )
-            ladder_k = 1
-        self._cand_ladder_k = ladder_k
-        #: In-program fall-throughs (snug branch overflowed, level re-ran
-        #: at full width inside the same dispatch) — the ladder's only
-        #: waste case, observable for tests and the A/B harness.
-        self.cand_retries = 0
-        # Expand-stage layout (an A/B knob
-        # for the chip window). "rows" materializes the [F, A, W] grid the
-        # vmap naturally produces, then transposes to [W, A*F] planes —
-        # the intermediate has W=2 on the minor axis, i.e. the (8,128)
-        # tiling tax on its full traffic. "planes" asks the vmap to emit
-        # [A, W, F] directly (out_axes=2), keeping F minor throughout —
-        # no padded intermediate. NOT default anywhere: a transpose fused
-        # INTO a vmapped kernel is the exact shape XLA:CPU (jax 0.9.0)
-        # miscompiles (_build_superstep_planes docstring), so "planes" is
-        # for accelerator A/Bs guarded by count_ok + the table audit.
-        expand_layout = os.environ.get("STPU_EXPAND_LAYOUT", "rows")
-        if expand_layout not in ("rows", "planes"):
-            raise ValueError(
-                f"STPU_EXPAND_LAYOUT must be 'rows' or 'planes': {expand_layout!r}"
-            )
-        if expand_layout == "planes" and not self._soa:
-            # The knob only exists in the planes superstep; an A/B run on
-            # the rows-major (hash-dedup) builder would silently measure
-            # two identical programs.
-            import warnings
+            self._expand_layout = expand_layout
 
-            warnings.warn(
-                "STPU_EXPAND_LAYOUT=planes has no effect with dedup='hash' "
-                "(rows-major superstep); the knob applies to the "
-                "sorted/delta planes engine only",
-                RuntimeWarning,
-                stacklevel=3,
+            self._max_probes = max_probes
+            self._W = model.state_words
+            self._A = model.max_actions
+            self._P = len(self._properties)
+            # Host-verified properties: device flags candidates, host confirms
+            # with the exact object-level condition (see module docstring).
+            hv_names = frozenset(getattr(model, "host_verified_properties", ()))
+            unknown = hv_names - {p.name for p in self._properties}
+            if unknown:
+                raise ValueError(f"host_verified_properties not in properties(): {unknown}")
+            self._hv_idx = [
+                i for i, p in enumerate(self._properties) if p.name in hv_names
+            ]
+            for i in self._hv_idx:
+                if self._properties[i].expectation == Expectation.EVENTUALLY:
+                    raise ValueError(
+                        "host-verified eventually-properties are not supported"
+                    )
+            # Candidate rows per super-step per host-verified property;
+            # spawn_xla(host_verified_cap=...) raises it for models whose
+            # conservative predicates flag wide swaths of the frontier.
+            self._hv_cap = host_verified_cap
+            # Per-level ceiling on host-side visitor path reconstruction.
+            self._visit_cap = visit_cap
+            # BFS levels fused into one device dispatch. Each host round-trip
+            # costs real latency, so the level loop runs *on device* in a ``lax.while_loop`` that exits
+            # early on frontier exhaustion, overflow, discovery resolution, or
+            # a state-count target — semantically identical to dispatching one
+            # level at a time, at level granularity. Visitors force 1 (they
+            # need the host between levels). The default of 32 was measured on
+            # an earlier chip setup and is to be re-measured (ROADMAP S5).
+            self._levels_per_dispatch = (
+                1 if self._visitor is not None else max(1, levels_per_dispatch)
             )
-        self._expand_layout = expand_layout
 
-        self._max_probes = max_probes
-        self._W = model.state_words
-        self._A = model.max_actions
-        self._P = len(self._properties)
-        # Host-verified properties: device flags candidates, host confirms
-        # with the exact object-level condition (see module docstring).
-        hv_names = frozenset(getattr(model, "host_verified_properties", ()))
-        unknown = hv_names - {p.name for p in self._properties}
-        if unknown:
-            raise ValueError(f"host_verified_properties not in properties(): {unknown}")
-        self._hv_idx = [
-            i for i, p in enumerate(self._properties) if p.name in hv_names
-        ]
-        for i in self._hv_idx:
-            if self._properties[i].expectation == Expectation.EVENTUALLY:
-                raise ValueError(
-                    "host-verified eventually-properties are not supported"
+            # --- device state ------------------------------------------------
+            import jax.numpy as jnp
+
+            self._disc_found = jnp.zeros(self._P, jnp.bool_)
+            self._disc_fp = jnp.zeros((self._P, 2), jnp.uint32)
+            self._found_names: Dict[str, int] = {}  # name -> fp64, pinned on first find
+            self._target_reached = False
+            # Compiled supersteps are a property of the MODEL (its kernels and
+            # properties), not of one checker run — cache on the model instance
+            # so repeated checks (bench warm/measure passes, retries) reuse
+            # compilations instead of paying a fresh XLA compile per bucket.
+            self._superstep_cache: Dict[Any, Any] = model.__dict__.setdefault(
+                "_xla_superstep_cache", {}
+            )
+
+            # Candidate-cap sizing is PER-CHECKER state seeded from per-model
+            # hints: the old model-level dict let two live checkers over one
+            # model object resize each other's candidate buffers mid-run
+            # (latent aliasing — a cc_ovf growth in checker A silently changed
+            # checker B's bucket shapes and evicted its compiled programs).
+            # Growths still write back to the model hint dict, so a FRESH
+            # checker (the bench measured pass) inherits learned caps and
+            # replays the warm pass's shapes instead of re-paying cc_ovf
+            # growth compiles.
+            self._cand_caps: Dict[int, int] = dict(
+                model.__dict__.get("_xla_cand_cap_hints", {})
+            )
+            # Live-checker registry (weakrefs): _grow_cand_cap consults it so
+            # a growth in this checker never evicts shared compiled programs
+            # a live sibling still sizes at the old cap.
+            import weakref
+
+            live = model.__dict__.setdefault("_xla_live_checkers", [])
+            live[:] = [r for r in live if r() is not None]
+            live.append(weakref.ref(self))
+
+            # Capacities learned by earlier checkers of this model (growth
+            # events) — starting there skips the rehash-and-rerun the previous
+            # run already paid (bench warm pass learns, measured pass reuses).
+            # Hints apply only when the caller took the defaults: an explicit
+            # capacity — even a smaller one, e.g. to exercise the growth path —
+            # must win over cross-checker state.
+            self._table_hint_key = f"_xla_table_cap_hint_{dedup}"
+            if table_capacity is None:
+                table_capacity = max(
+                    1 << 20, model.__dict__.get(self._table_hint_key, 0)
                 )
-        # Candidate rows per super-step per host-verified property;
-        # spawn_xla(host_verified_cap=...) raises it for models whose
-        # conservative predicates flag wide swaths of the frontier.
-        self._hv_cap = host_verified_cap
-        # Per-level ceiling on host-side visitor path reconstruction.
-        self._visit_cap = visit_cap
-        # BFS levels fused into one device dispatch. Each host round-trip
-        # costs real latency, so the level loop runs *on device* in a ``lax.while_loop`` that exits
-        # early on frontier exhaustion, overflow, discovery resolution, or
-        # a state-count target — semantically identical to dispatching one
-        # level at a time, at level granularity. Visitors force 1 (they
-        # need the host between levels). The default of 32 was measured on
-        # an earlier chip setup and is to be re-measured (ROADMAP S5).
-        self._levels_per_dispatch = (
-            1 if self._visitor is not None else max(1, levels_per_dispatch)
-        )
+            if frontier_capacity is None:
+                frontier_capacity = max(
+                    1 << 15, model.__dict__.get("_xla_frontier_cap_hint", 0)
+                )
 
-        # --- device state ------------------------------------------------
-        import jax.numpy as jnp
+            # Per-level telemetry ({depth, frontier, generated, unique} per
+            # committed BFS level) — populated by both dispatch paths so fused
+            # dispatch does not cost consumers (bench_detail.json) the
+            # per-level breakdown.
+            self.level_log: List[Dict[str, int]] = []
+            # Dispatch telemetry — ONE shape for every engine and dispatch
+            # flavor (pinned by tests/test_obs.py, documented in
+            # docs/observability.md): one ``(run_cap, committed_levels)``
+            # tuple per device call, where ``committed_levels`` is the number
+            # of BFS levels that call committed. The one-level path therefore
+            # records 0 or 1 (0 = an overflow retry of the same level); a
+            # fused block records 0..levels_per_dispatch. Invariant on both:
+            # ``sum(committed for _, committed in dispatch_log) ==
+            # len(level_log)``. Makes the bucket ladder's choices (jump
+            # rungs, tail shrink-exits, lpd=1 snug picks) observable to
+            # tests, metrics(), and the superstep profiler.
+            self.dispatch_log: List[Tuple[int, int]] = []
+            # Host-verified-path telemetry (the sampled-predicate cliff,
+            # the hv cost question): how much the conservative device predicate
+            # over-flags and what the exact host confirmations cost.
+            #   flagged      rows the device pass could not clear (sum of
+            #                per-superstep candidate counts, pre-cap)
+            #   host_checked rows the host serializer actually re-checked
+            #   cleared      checked rows that proved serializable (= the
+            #                predicate's false alarms, pure overhead)
+            #   confirmed    checked rows that confirmed a discovery
+            #   host_sec     wall-clock spent in exact host confirmation
+            self.hv_stats: Dict[str, float] = {
+                "flagged": 0, "host_checked": 0, "cleared": 0,
+                "confirmed": 0, "host_sec": 0.0,
+            }
 
-        self._disc_found = jnp.zeros(self._P, jnp.bool_)
-        self._disc_fp = jnp.zeros((self._P, 2), jnp.uint32)
-        self._found_names: Dict[str, int] = {}  # name -> fp64, pinned on first find
-        self._target_reached = False
-        # Compiled supersteps are a property of the MODEL (its kernels and
-        # properties), not of one checker run — cache on the model instance
-        # so repeated checks (bench warm/measure passes, retries) reuse
-        # compilations instead of paying a fresh XLA compile per bucket.
-        self._superstep_cache: Dict[Any, Any] = model.__dict__.setdefault(
-            "_xla_superstep_cache", {}
-        )
+            if checkpoint is not None:
+                # Skip init seeding entirely; _restore builds the whole state.
+                self._frontier_capacity = max(frontier_capacity, 16)
+                self._table = self._ds.make(table_capacity, jnp)
+                self._restore(checkpoint)
+                if self._autockpt is not None:
+                    self._autockpt.arm(self._depth)
+                if self._recorder is not None:
+                    self._recorder.arm(self._depth)
+                return
 
-        # Candidate-cap sizing is PER-CHECKER state seeded from per-model
-        # hints: the old model-level dict let two live checkers over one
-        # model object resize each other's candidate buffers mid-run
-        # (latent aliasing — a cc_ovf growth in checker A silently changed
-        # checker B's bucket shapes and evicted its compiled programs).
-        # Growths still write back to the model hint dict, so a FRESH
-        # checker (the bench measured pass) inherits learned caps and
-        # replays the warm pass's shapes instead of re-paying cc_ovf
-        # growth compiles.
-        self._cand_caps: Dict[int, int] = dict(
-            model.__dict__.get("_xla_cand_cap_hints", {})
-        )
-        # Live-checker registry (weakrefs): _grow_cand_cap consults it so
-        # a growth in this checker never evicts shared compiled programs
-        # a live sibling still sizes at the old cap.
-        import weakref
+            init_packed = np.asarray(model.packed_init(), dtype=np.uint32)
+            # Boundary filter on init states (bfs.rs:52-56) is the model's
+            # responsibility at packed_init time; the object-level default
+            # applies it here for safety.
+            keep = [model.within_boundary(model.unpack(row)) for row in init_packed]
+            init_packed = init_packed[keep]
+            n_init = len(init_packed)
 
-        live = model.__dict__.setdefault("_xla_live_checkers", [])
-        live[:] = [r for r in live if r() is not None]
-        live.append(weakref.ref(self))
+            self._frontier_capacity = max(frontier_capacity, 1 << max(n_init.bit_length(), 4))
+            with self._tracer.span("spawn:table"):
+                self._table = self._ds.make(table_capacity, jnp)
+            # Insert init fingerprints with a zero parent (the "no predecessor"
+            # marker, like the None predecessor of bfs.rs:59-65).
+            with self._tracer.span("spawn:init_insert"):
+                dedup_init = self._dedup_words_host(init_packed)
+                ihi, ilo = fphash.fingerprint_words(dedup_init, np)
+                self._table, is_new, ovf = self._ds.insert(
+                    self._table,
+                    jnp.asarray(ihi),
+                    jnp.asarray(ilo),
+                    jnp.zeros(n_init, jnp.uint32),
+                    jnp.zeros(n_init, jnp.uint32),
+                    jnp.ones(n_init, jnp.bool_),
+                    max_probes=self._max_probes,
+                )
+                if bool(np.any(np.asarray(ovf))):  # pragma: no cover - tiny tables only
+                    raise RuntimeError("visited-set overflow while inserting init states")
+                n_unique_init = int(np.sum(np.asarray(is_new)))
 
-        # Capacities learned by earlier checkers of this model (growth
-        # events) — starting there skips the rehash-and-rerun the previous
-        # run already paid (bench warm pass learns, measured pass reuses).
-        # Hints apply only when the caller took the defaults: an explicit
-        # capacity — even a smaller one, e.g. to exercise the growth path —
-        # must win over cross-checker state.
-        self._table_hint_key = f"_xla_table_cap_hint_{dedup}"
-        if table_capacity is None:
-            table_capacity = max(
-                1 << 20, model.__dict__.get(self._table_hint_key, 0)
-            )
-        if frontier_capacity is None:
-            frontier_capacity = max(
-                1 << 15, model.__dict__.get("_xla_frontier_cap_hint", 0)
-            )
-
-        # Per-level telemetry ({depth, frontier, generated, unique} per
-        # committed BFS level) — populated by both dispatch paths so fused
-        # dispatch does not cost consumers (bench_detail.json) the
-        # per-level breakdown.
-        self.level_log: List[Dict[str, int]] = []
-        # Dispatch telemetry — ONE shape for every engine and dispatch
-        # flavor (pinned by tests/test_obs.py, documented in
-        # docs/observability.md): one ``(run_cap, committed_levels)``
-        # tuple per device call, where ``committed_levels`` is the number
-        # of BFS levels that call committed. The one-level path therefore
-        # records 0 or 1 (0 = an overflow retry of the same level); a
-        # fused block records 0..levels_per_dispatch. Invariant on both:
-        # ``sum(committed for _, committed in dispatch_log) ==
-        # len(level_log)``. Makes the bucket ladder's choices (jump
-        # rungs, tail shrink-exits, lpd=1 snug picks) observable to
-        # tests, metrics(), and the superstep profiler.
-        self.dispatch_log: List[Tuple[int, int]] = []
-        # Host-verified-path telemetry (the sampled-predicate cliff,
-        # the hv cost question): how much the conservative device predicate
-        # over-flags and what the exact host confirmations cost.
-        #   flagged      rows the device pass could not clear (sum of
-        #                per-superstep candidate counts, pre-cap)
-        #   host_checked rows the host serializer actually re-checked
-        #   cleared      checked rows that proved serializable (= the
-        #                predicate's false alarms, pure overhead)
-        #   confirmed    checked rows that confirmed a discovery
-        #   host_sec     wall-clock spent in exact host confirmation
-        self.hv_stats: Dict[str, float] = {
-            "flagged": 0, "host_checked": 0, "cleared": 0,
-            "confirmed": 0, "host_sec": 0.0,
-        }
-
-        if checkpoint is not None:
-            # Skip init seeding entirely; _restore builds the whole state.
-            self._frontier_capacity = max(frontier_capacity, 16)
-            self._table = self._ds.make(table_capacity, jnp)
-            self._restore(checkpoint)
+            with self._tracer.span("spawn:frontier"):
+                self._frontier = self._pad_rows(init_packed, self._frontier_capacity)
+                self._frontier_ebits = jnp.where(
+                    jnp.arange(self._frontier_capacity) < n_init, jnp.uint32(self._ebits0), jnp.uint32(0)
+                )
+            self._frontier_count = n_init
+            self._depth = 1  # depth of states in the current frontier (bfs.rs:83)
+            self._max_depth = 0
+            self._state_count = n_init
+            self._unique_count = n_unique_init
+            self._exhausted = n_init == 0
             if self._autockpt is not None:
                 self._autockpt.arm(self._depth)
             if self._recorder is not None:
                 self._recorder.arm(self._depth)
-            return
-
-        init_packed = np.asarray(model.packed_init(), dtype=np.uint32)
-        # Boundary filter on init states (bfs.rs:52-56) is the model's
-        # responsibility at packed_init time; the object-level default
-        # applies it here for safety.
-        keep = [model.within_boundary(model.unpack(row)) for row in init_packed]
-        init_packed = init_packed[keep]
-        n_init = len(init_packed)
-
-        self._frontier_capacity = max(frontier_capacity, 1 << max(n_init.bit_length(), 4))
-        self._table = self._ds.make(table_capacity, jnp)
-        # Insert init fingerprints with a zero parent (the "no predecessor"
-        # marker, like the None predecessor of bfs.rs:59-65).
-        dedup_init = self._dedup_words_host(init_packed)
-        ihi, ilo = fphash.fingerprint_words(dedup_init, np)
-        self._table, is_new, ovf = self._ds.insert(
-            self._table,
-            jnp.asarray(ihi),
-            jnp.asarray(ilo),
-            jnp.zeros(n_init, jnp.uint32),
-            jnp.zeros(n_init, jnp.uint32),
-            jnp.ones(n_init, jnp.bool_),
-            max_probes=self._max_probes,
-        )
-        if bool(np.any(np.asarray(ovf))):  # pragma: no cover - tiny tables only
-            raise RuntimeError("visited-set overflow while inserting init states")
-        n_unique_init = int(np.sum(np.asarray(is_new)))
-
-        self._frontier = self._pad_rows(init_packed, self._frontier_capacity)
-        self._frontier_ebits = jnp.where(
-            jnp.arange(self._frontier_capacity) < n_init, jnp.uint32(self._ebits0), jnp.uint32(0)
-        )
-        self._frontier_count = n_init
-        self._depth = 1  # depth of states in the current frontier (bfs.rs:83)
-        self._max_depth = 0
-        self._state_count = n_init
-        self._unique_count = n_unique_init
-        self._exhausted = n_init == 0
-        if self._autockpt is not None:
-            self._autockpt.arm(self._depth)
-        if self._recorder is not None:
-            self._recorder.arm(self._depth)
 
     # --- checkpoint/resume (stateright_tpu/checkpoint.py) ------------------
 
@@ -1388,31 +1369,37 @@ class XlaChecker(Checker):
             return hv_compact
 
         def superstep(frontier, f_ebits, f_count, table, disc_found, disc_fp):
-            # frontier: [F, W] rows (kernel-facing boundary).
+            # frontier: [F, W] rows (kernel-facing boundary). Each stage
+            # runs under a jax.named_scope of its name, so its operations
+            # carry the name in their op_name metadata and in a profiler
+            # trace; the ladder's switch branches all call this function.
             f_valid = jnp.arange(f_cap) < f_count
-            dw = jax.vmap(dedup_words)(frontier)
-            fhi, flo = fphash.fingerprint_words(dw, jnp)
+            with jax.named_scope("fingerprint"):
+                dw = jax.vmap(dedup_words)(frontier)
+                fhi, flo = fphash.fingerprint_words(dw, jnp)
 
             # 1. fused property evaluation over the frontier.
-            props = jax.vmap(model.packed_properties)(frontier)  # [F, P]
-            f_ebits, disc_found, disc_fp, (hv_words, hv_fps, hv_counts) = (
-                eval_properties(
-                    props, f_valid, f_ebits, fhi, flo, disc_found, disc_fp,
-                    hv_compact_planes(frontier, fhi, flo), jnp,
+            with jax.named_scope("properties"):
+                props = jax.vmap(model.packed_properties)(frontier)  # [F, P]
+                f_ebits, disc_found, disc_fp, (hv_words, hv_fps, hv_counts) = (
+                    eval_properties(
+                        props, f_valid, f_ebits, fhi, flo, disc_found, disc_fp,
+                        hv_compact_planes(frontier, fhi, flo), jnp,
+                    )
                 )
-            )
 
             # 2. action-grid expansion; codec overflow folded in as in
             #    rows mode. Layout per the STPU_EXPAND_LAYOUT knob (see
             #    __init__): "rows" = [F, A, W] + materialized transpose,
             #    "planes" = the vmap emits [A, W, F] with F minor.
-            if self._expand_layout == "planes":
-                nxt, valid, step_ovf = jax.vmap(step3, out_axes=(2, 0, 0))(frontier)
-            else:
-                nxt, valid, step_ovf = jax.vmap(step3)(frontier)
-            codec_overflow = jnp.any(step_ovf & f_valid[:, None])
-            valid = valid & f_valid[:, None]
-            step_states = jnp.sum(valid, dtype=jnp.int32)
+            with jax.named_scope("expand"):
+                if self._expand_layout == "planes":
+                    nxt, valid, step_ovf = jax.vmap(step3, out_axes=(2, 0, 0))(frontier)
+                else:
+                    nxt, valid, step_ovf = jax.vmap(step3)(frontier)
+                codec_overflow = jnp.any(step_ovf & f_valid[:, None])
+                valid = valid & f_valid[:, None]
+                step_states = jnp.sum(valid, dtype=jnp.int32)
 
             # 3. flatten the grid into [W, A*F] planes and compact in
             #    state-major rank order. Under the sort/gather compactions
@@ -1423,107 +1410,112 @@ class XlaChecker(Checker):
             #    array order IS semantic order and the compaction needs no
             #    sort at all; the [.., F, A] intermediate's minor-axis
             #    padding is fused away into the reshape consumer.
-            if compaction in ("bsearch", "pallas"):
-                if self._expand_layout == "planes":
-                    grid = jnp.transpose(nxt, (1, 2, 0)).reshape(W, f_cap * A)
+            with jax.named_scope("compact"):
+                if compaction in ("bsearch", "pallas"):
+                    if self._expand_layout == "planes":
+                        grid = jnp.transpose(nxt, (1, 2, 0)).reshape(W, f_cap * A)
+                    else:
+                        grid = jnp.transpose(nxt, (2, 0, 1)).reshape(W, f_cap * A)
+                    vmask = valid.reshape(f_cap * A)
+                    par_hi = jnp.broadcast_to(fhi[:, None], (f_cap, A)).reshape(-1)
+                    par_lo = jnp.broadcast_to(flo[:, None], (f_cap, A)).reshape(-1)
+                    child_ebits = jnp.broadcast_to(
+                        f_ebits[:, None], (f_cap, A)
+                    ).reshape(-1)
+                    prio = None
                 else:
-                    grid = jnp.transpose(nxt, (2, 0, 1)).reshape(W, f_cap * A)
-                vmask = valid.reshape(f_cap * A)
-                par_hi = jnp.broadcast_to(fhi[:, None], (f_cap, A)).reshape(-1)
-                par_lo = jnp.broadcast_to(flo[:, None], (f_cap, A)).reshape(-1)
-                child_ebits = jnp.broadcast_to(
-                    f_ebits[:, None], (f_cap, A)
-                ).reshape(-1)
-                prio = None
-            else:
-                if self._expand_layout == "planes":
-                    # [A, W, F] -> [W, A, F] moves whole F-contiguous lanes:
-                    # tiling-friendly, no (8,128)-padded intermediate.
-                    grid = jnp.transpose(nxt, (1, 0, 2)).reshape(W, A * f_cap)
+                    if self._expand_layout == "planes":
+                        # [A, W, F] -> [W, A, F] moves whole F-contiguous lanes:
+                        # tiling-friendly, no (8,128)-padded intermediate.
+                        grid = jnp.transpose(nxt, (1, 0, 2)).reshape(W, A * f_cap)
+                    else:
+                        grid = jnp.transpose(nxt, (2, 1, 0)).reshape(W, A * f_cap)
+                    vmask = valid.T.reshape(A * f_cap)
+                    par_hi = jnp.broadcast_to(fhi[None, :], (A, f_cap)).reshape(-1)
+                    par_lo = jnp.broadcast_to(flo[None, :], (A, f_cap)).reshape(-1)
+                    child_ebits = jnp.broadcast_to(
+                        f_ebits[None, :], (A, f_cap)
+                    ).reshape(-1)
+                    j = jnp.arange(A * f_cap, dtype=jnp.int32)
+                    prio = (j % f_cap) * A + (j // f_cap)  # semantic rank f*A + a
+                if compaction == "sort":
+                    # The grid sort is the engine's largest per-level op (A*F
+                    # lanes; ~60% of the sorted lane-words at rm=8 shapes), and
+                    # the parent-fp/ebits payloads are pure functions of the
+                    # winning priority key (state-major rank k -> parent row
+                    # k // A) — so sort ONLY key + state planes and recover
+                    # parents/ebits by [cand_cap]-sized gathers from the
+                    # [F]-sized frontier arrays afterwards. Bit-identical to
+                    # carrying them as payload; removes 3 of the W+4 operands
+                    # from the dominant sort.
+                    m_grid = A * f_cap
+                    gkey = jnp.where(vmask, prio, prio + jnp.int32(1 << 30))
+                    take = min(cand_cap, m_grid)
+                    sorted_all = jax.lax.sort(
+                        (gkey, *[grid[w] for w in range(W)]),
+                        num_keys=1, is_stable=True,
+                    )
+                    skey = sorted_all[0][:take]
+                    smask = skey < jnp.int32(1 << 30)
+                    k_rank = (skey & jnp.int32((1 << 30) - 1)) // jnp.int32(A)
+                    f_row = jnp.clip(k_rank, 0, f_cap - 1)
+                    z32 = jnp.uint32(0)
+
+                    def pad_lane(lane):
+                        lane = jnp.where(smask, lane, z32)
+                        if take < cand_cap:
+                            lane = jnp.concatenate(
+                                [lane, jnp.zeros((cand_cap - take,), lane.dtype)]
+                            )
+                        return lane
+
+                    ccand = jnp.stack(
+                        [pad_lane(s[:take]) for s in sorted_all[1:]]
+                    )
+                    cpar_hi = pad_lane(fhi[f_row])
+                    cpar_lo = pad_lane(flo[f_row])
+                    cebits = pad_lane(f_ebits[f_row])
+                    n_valid = jnp.sum(vmask, dtype=jnp.int32)
                 else:
-                    grid = jnp.transpose(nxt, (2, 1, 0)).reshape(W, A * f_cap)
-                vmask = valid.T.reshape(A * f_cap)
-                par_hi = jnp.broadcast_to(fhi[None, :], (A, f_cap)).reshape(-1)
-                par_lo = jnp.broadcast_to(flo[None, :], (A, f_cap)).reshape(-1)
-                child_ebits = jnp.broadcast_to(
-                    f_ebits[None, :], (A, f_cap)
-                ).reshape(-1)
-                j = jnp.arange(A * f_cap, dtype=jnp.int32)
-                prio = (j % f_cap) * A + (j // f_cap)  # semantic rank f*A + a
-            if compaction == "sort":
-                # The grid sort is the engine's largest per-level op (A*F
-                # lanes; ~60% of the sorted lane-words at rm=8 shapes), and
-                # the parent-fp/ebits payloads are pure functions of the
-                # winning priority key (state-major rank k -> parent row
-                # k // A) — so sort ONLY key + state planes and recover
-                # parents/ebits by [cand_cap]-sized gathers from the
-                # [F]-sized frontier arrays afterwards. Bit-identical to
-                # carrying them as payload; removes 3 of the W+4 operands
-                # from the dominant sort.
-                m_grid = A * f_cap
-                gkey = jnp.where(vmask, prio, prio + jnp.int32(1 << 30))
-                take = min(cand_cap, m_grid)
-                sorted_all = jax.lax.sort(
-                    (gkey, *[grid[w] for w in range(W)]),
-                    num_keys=1, is_stable=True,
-                )
-                skey = sorted_all[0][:take]
-                smask = skey < jnp.int32(1 << 30)
-                k_rank = (skey & jnp.int32((1 << 30) - 1)) // jnp.int32(A)
-                f_row = jnp.clip(k_rank, 0, f_cap - 1)
-                z32 = jnp.uint32(0)
-
-                def pad_lane(lane):
-                    lane = jnp.where(smask, lane, z32)
-                    if take < cand_cap:
-                        lane = jnp.concatenate(
-                            [lane, jnp.zeros((cand_cap - take,), lane.dtype)]
-                        )
-                    return lane
-
-                ccand = jnp.stack(
-                    [pad_lane(s[:take]) for s in sorted_all[1:]]
-                )
-                cpar_hi = pad_lane(fhi[f_row])
-                cpar_lo = pad_lane(flo[f_row])
-                cebits = pad_lane(f_ebits[f_row])
-                n_valid = jnp.sum(vmask, dtype=jnp.int32)
-            else:
-                (ccand, cpar_hi, cpar_lo, cebits), n_valid = compact_1d(
-                    vmask, cand_cap, [grid, par_hi, par_lo, child_ebits],
-                    prio=prio,
-                )
-            cvalid = jnp.arange(cand_cap) < n_valid
-            cand_overflow = n_valid > cand_cap
-            if symmetry:
-                # The representative kernel needs [W] rows; gather candidate
-                # rows once (symmetry models only — the common case keeps
-                # candidates pure plane-major).
-                crows = jnp.stack([ccand[w] for w in range(W)], axis=1)
-                cdw = jax.vmap(dedup_words)(crows)
-                chi, clo = fphash.fingerprint_words(cdw, jnp)
-            else:
-                chi, clo = fphash.fingerprint_planes(ccand, jnp)
+                    (ccand, cpar_hi, cpar_lo, cebits), n_valid = compact_1d(
+                        vmask, cand_cap, [grid, par_hi, par_lo, child_ebits],
+                        prio=prio,
+                    )
+                cvalid = jnp.arange(cand_cap) < n_valid
+                cand_overflow = n_valid > cand_cap
+            with jax.named_scope("fingerprint"):
+                if symmetry:
+                    # The representative kernel needs [W] rows; gather candidate
+                    # rows once (symmetry models only — the common case keeps
+                    # candidates pure plane-major).
+                    crows = jnp.stack([ccand[w] for w in range(W)], axis=1)
+                    cdw = jax.vmap(dedup_words)(crows)
+                    chi, clo = fphash.fingerprint_words(cdw, jnp)
+                else:
+                    chi, clo = fphash.fingerprint_planes(ccand, jnp)
 
             # 4. dedup (candidates are in state-major order, so the insert's
             #    default arange ticket IS the semantic winner election).
-            table, is_new, ovf = ds.insert(
-                table, chi, clo, cpar_hi, cpar_lo, cvalid, max_probes=max_probes
-            )
-            step_unique = jnp.sum(is_new, dtype=jnp.int32)
-            table_overflow = jnp.any(ovf)
+            with jax.named_scope("insert"):
+                table, is_new, ovf = ds.insert(
+                    table, chi, clo, cpar_hi, cpar_lo, cvalid, max_probes=max_probes
+                )
+                step_unique = jnp.sum(is_new, dtype=jnp.int32)
+                table_overflow = jnp.any(ovf)
 
             # 5. terminal detection for eventually counterexamples.
-            terminal = f_valid & ~jnp.any(valid, axis=1)
-            disc_found, disc_fp = terminal_pass(
-                terminal, f_ebits, fhi, flo, disc_found, disc_fp, jnp
-            )
+            with jax.named_scope("terminal"):
+                terminal = f_valid & ~jnp.any(valid, axis=1)
+                disc_found, disc_fp = terminal_pass(
+                    terminal, f_ebits, fhi, flo, disc_found, disc_fp, jnp
+                )
 
             # 6. survivors -> next frontier rows (stable: semantic order).
-            (new_frontier, new_ebits), new_count = compact_1d(
-                is_new, out_cap, [ccand, cebits], rows_out=(0,)
-            )
-            frontier_overflow = new_count > out_cap
+            with jax.named_scope("compact"):
+                (new_frontier, new_ebits), new_count = compact_1d(
+                    is_new, out_cap, [ccand, cebits], rows_out=(0,)
+                )
+                frontier_overflow = new_count > out_cap
 
             return (
                 new_frontier,
@@ -1588,7 +1580,12 @@ class XlaChecker(Checker):
         a main-capacity sort, and a ladder branch carries the [table ‖
         cand] merge sort — the TPU-target lowering pre-flights clean
         (tests/test_cand_ladder.py), and chip_smoke.py runs it on the
-        chip (the cand_ladder=3 default)."""
+        chip (the cand_ladder=3 default).
+
+        The loop's own per-level work (the exit test, rung selection, the
+        commit select of the carry, level telemetry and the host-verified
+        accumulation) runs under ``jax.named_scope("ladder")``, beside the
+        superstep's named stages."""
         import jax
         import jax.numpy as jnp
 
@@ -1673,26 +1670,27 @@ class XlaChecker(Checker):
                 # for. Total iterations stay bounded — every non-commit
                 # either sets an overflow flag (exit) or force_full, and a
                 # forced full-width level commits or overflows.
-                return (
-                    (committed < budget)
-                    & (f_count > 0)
-                    # Shrink-exit: once the frontier collapses below the
-                    # host-chosen threshold (derived from smaller buckets
-                    # that already hold compiled programs — 0 disables),
-                    # hand control back so the tail levels re-dispatch at
-                    # a snug bucket instead of paying this bucket's full
-                    # A*F-lane grid compaction per level. The committed==0
-                    # bypass guarantees one committed level per entry: a
-                    # frontier-overflow grow can land here with f_count
-                    # already at or below the outgrown bucket's threshold,
-                    # and exiting at level 0 would stall the checker in a
-                    # grow/stall/re-enter cycle forever.
-                    & ((committed == 0) | (f_count > shrink_below))
-                    & ~jnp.any(ovf)
-                    & ~resolved(disc_found, hv_c)
-                    & ~hv_pending(hv_c)
-                    & (tot_states < remaining)
-                )
+                with jax.named_scope("ladder"):
+                    return (
+                        (committed < budget)
+                        & (f_count > 0)
+                        # Shrink-exit: once the frontier collapses below the
+                        # host-chosen threshold (derived from smaller buckets
+                        # that already hold compiled programs — 0 disables),
+                        # hand control back so the tail levels re-dispatch at
+                        # a snug bucket instead of paying this bucket's full
+                        # A*F-lane grid compaction per level. The committed==0
+                        # bypass guarantees one committed level per entry: a
+                        # frontier-overflow grow can land here with f_count
+                        # already at or below the outgrown bucket's threshold,
+                        # and exiting at level 0 would stall the checker in a
+                        # grow/stall/re-enter cycle forever.
+                        & ((committed == 0) | (f_count > shrink_below))
+                        & ~jnp.any(ovf)
+                        & ~resolved(disc_found, hv_c)
+                        & ~hv_pending(hv_c)
+                        & (tot_states < remaining)
+                    )
 
             def body(carry):
                 (committed, frontier, f_ebits, f_count, table, disc_found,
@@ -1706,99 +1704,101 @@ class XlaChecker(Checker):
                         frontier, f_ebits, f_count, table, disc_found, disc_fp
                     )
                 else:
-                    # Branch selection. ``bound`` is the exact candidate
-                    # ceiling (every grid slot valid); the extrapolated
-                    # estimate may pick a snugger rung, and the frontier
-                    # constraint F_k >= f_count is always exact.
-                    bound = f_count * jnp.int32(A)
-                    growth = jnp.clip(
-                        prev_gen.astype(jnp.float32)
-                        / jnp.maximum(prev2_gen, 1).astype(jnp.float32),
-                        1.0,
-                        growth_clamp,
-                    )
-                    est = prev_gen.astype(jnp.float32) * growth * cand_margin
-                    est_i = jnp.minimum(est, jnp.float32(2**30)).astype(
-                        jnp.int32
-                    )
-                    need = jnp.where(
-                        prev_gen > 0, jnp.minimum(bound, est_i), bound
-                    )
-                    k = jnp.int32(K - 1)
-                    for j in range(K - 2, -1, -1):
-                        ok = (f_count <= F_rungs[j]) & (need <= C_rungs[j])
-                        k = jnp.where(ok, jnp.int32(j), k)
-                    k = jnp.where(force_full, jnp.int32(K - 1), k)
+                    with jax.named_scope("ladder"):
+                        # Branch selection. ``bound`` is the exact candidate
+                        # ceiling (every grid slot valid); the extrapolated
+                        # estimate may pick a snugger rung, and the frontier
+                        # constraint F_k >= f_count is always exact.
+                        bound = f_count * jnp.int32(A)
+                        growth = jnp.clip(
+                            prev_gen.astype(jnp.float32)
+                            / jnp.maximum(prev2_gen, 1).astype(jnp.float32),
+                            1.0,
+                            growth_clamp,
+                        )
+                        est = prev_gen.astype(jnp.float32) * growth * cand_margin
+                        est_i = jnp.minimum(est, jnp.float32(2**30)).astype(
+                            jnp.int32
+                        )
+                        need = jnp.where(
+                            prev_gen > 0, jnp.minimum(bound, est_i), bound
+                        )
+                        k = jnp.int32(K - 1)
+                        for j in range(K - 2, -1, -1):
+                            ok = (f_count <= F_rungs[j]) & (need <= C_rungs[j])
+                            k = jnp.where(ok, jnp.int32(j), k)
+                        k = jnp.where(force_full, jnp.int32(K - 1), k)
                     out = jax.lax.switch(
                         k, branches, frontier, f_ebits, f_count, table,
                         disc_found, disc_fp,
                     )
-                (nf, ne, ncount, ntable, ndfound, ndfp, d_states, d_unique,
-                 t_ovf, f_ovf, c_ovf, cc_ovf, lw, lf, lc) = out
-                # A snug branch's candidate overflow is the ladder's
-                # fall-through, not a host event: the level is simply not
-                # committed and the next iteration is forced full-width.
-                # Only the full-width branch's overflow is the real
-                # cc_ovf the host grows on.
-                sub_ovf = cc_ovf & (k < K - 1)
-                real_cc = cc_ovf & (k == K - 1)
-                any_ovf = t_ovf | f_ovf | c_ovf | real_cc
-                commit = ~any_ovf & ~sub_ovf
-                sel = lambda new, old: jax.tree_util.tree_map(
-                    lambda a, b: jnp.where(commit, a, b), new, old
-                )
-                # Telemetry for this level, recorded only when committed
-                # (an uncommitted level is retried after growth): slot index
-                # L drops the write.
-                slot = jnp.where(commit, committed, L)
-                lvl_frontier = lvl_frontier.at[slot].set(f_count, mode="drop")
-                lvl_states = lvl_states.at[slot].set(d_states, mode="drop")
-                lvl_unique = lvl_unique.at[slot].set(d_unique, mode="drop")
-                lvl_bucket = lvl_bucket.at[slot].set(F_rungs[k], mode="drop")
-                lvl_cand = lvl_cand.at[slot].set(C_rungs[k], mode="drop")
-                # Append this level's host-verified candidates to the block
-                # accumulator (frontier order within a level, level order
-                # across the block — the confirmation order the one-level
-                # path uses).
-                if n_hv:
-                    rows = jnp.arange(hv_cap)
-                    for j in range(n_hv):
-                        dst = hv_c[j] + rows
-                        ok = (rows < lc[j]) & (dst < hv_cap)
-                        tgt = jnp.where(ok, dst, hv_cap)
-                        hv_w = hv_w.at[j].set(hv_w[j].at[tgt].set(lw[j], mode="drop"))
-                        hv_f = hv_f.at[j].set(hv_f[j].at[tgt].set(lf[j], mode="drop"))
-                    hv_c = sel(hv_c + lc, hv_c)
-                    hv_w = sel(hv_w, hv_w0)
-                    hv_f = sel(hv_f, hv_f0)
-                return (
-                    committed + commit.astype(jnp.int32),
-                    sel(nf, frontier),
-                    sel(ne, f_ebits),
-                    sel(ncount, f_count),
-                    sel(ntable, table),
-                    sel(ndfound, disc_found),
-                    sel(ndfp, disc_fp),
-                    tot_states + jnp.where(commit, d_states, 0),
-                    tot_unique + jnp.where(commit, d_unique, 0),
-                    jnp.stack([t_ovf, f_ovf, c_ovf, real_cc]),
-                    hv_w,
-                    hv_f,
-                    hv_c,
-                    lvl_frontier,
-                    lvl_states,
-                    lvl_unique,
-                    lvl_bucket,
-                    lvl_cand,
-                    jnp.where(commit, d_states, prev_gen),
-                    jnp.where(commit, prev_gen, prev2_gen),
-                    jnp.where(commit, jnp.bool_(False), force_full | sub_ovf),
-                    # Count only fall-throughs that actually re-run
-                    # in-program: a snug cc_ovf coinciding with a REAL
-                    # overflow exits the loop instead (the host resolves
-                    # it and the level re-runs on the next dispatch).
-                    retries + (sub_ovf & ~any_ovf).astype(jnp.int32),
-                )
+                with jax.named_scope("ladder"):
+                    (nf, ne, ncount, ntable, ndfound, ndfp, d_states, d_unique,
+                     t_ovf, f_ovf, c_ovf, cc_ovf, lw, lf, lc) = out
+                    # A snug branch's candidate overflow is the ladder's
+                    # fall-through, not a host event: the level is simply not
+                    # committed and the next iteration is forced full-width.
+                    # Only the full-width branch's overflow is the real
+                    # cc_ovf the host grows on.
+                    sub_ovf = cc_ovf & (k < K - 1)
+                    real_cc = cc_ovf & (k == K - 1)
+                    any_ovf = t_ovf | f_ovf | c_ovf | real_cc
+                    commit = ~any_ovf & ~sub_ovf
+                    sel = lambda new, old: jax.tree_util.tree_map(
+                        lambda a, b: jnp.where(commit, a, b), new, old
+                    )
+                    # Telemetry for this level, recorded only when committed
+                    # (an uncommitted level is retried after growth): slot index
+                    # L drops the write.
+                    slot = jnp.where(commit, committed, L)
+                    lvl_frontier = lvl_frontier.at[slot].set(f_count, mode="drop")
+                    lvl_states = lvl_states.at[slot].set(d_states, mode="drop")
+                    lvl_unique = lvl_unique.at[slot].set(d_unique, mode="drop")
+                    lvl_bucket = lvl_bucket.at[slot].set(F_rungs[k], mode="drop")
+                    lvl_cand = lvl_cand.at[slot].set(C_rungs[k], mode="drop")
+                    # Append this level's host-verified candidates to the block
+                    # accumulator (frontier order within a level, level order
+                    # across the block — the confirmation order the one-level
+                    # path uses).
+                    if n_hv:
+                        rows = jnp.arange(hv_cap)
+                        for j in range(n_hv):
+                            dst = hv_c[j] + rows
+                            ok = (rows < lc[j]) & (dst < hv_cap)
+                            tgt = jnp.where(ok, dst, hv_cap)
+                            hv_w = hv_w.at[j].set(hv_w[j].at[tgt].set(lw[j], mode="drop"))
+                            hv_f = hv_f.at[j].set(hv_f[j].at[tgt].set(lf[j], mode="drop"))
+                        hv_c = sel(hv_c + lc, hv_c)
+                        hv_w = sel(hv_w, hv_w0)
+                        hv_f = sel(hv_f, hv_f0)
+                    return (
+                        committed + commit.astype(jnp.int32),
+                        sel(nf, frontier),
+                        sel(ne, f_ebits),
+                        sel(ncount, f_count),
+                        sel(ntable, table),
+                        sel(ndfound, disc_found),
+                        sel(ndfp, disc_fp),
+                        tot_states + jnp.where(commit, d_states, 0),
+                        tot_unique + jnp.where(commit, d_unique, 0),
+                        jnp.stack([t_ovf, f_ovf, c_ovf, real_cc]),
+                        hv_w,
+                        hv_f,
+                        hv_c,
+                        lvl_frontier,
+                        lvl_states,
+                        lvl_unique,
+                        lvl_bucket,
+                        lvl_cand,
+                        jnp.where(commit, d_states, prev_gen),
+                        jnp.where(commit, prev_gen, prev2_gen),
+                        jnp.where(commit, jnp.bool_(False), force_full | sub_ovf),
+                        # Count only fall-throughs that actually re-run
+                        # in-program: a snug cc_ovf coinciding with a REAL
+                        # overflow exits the loop instead (the host resolves
+                        # it and the level re-runs on the next dispatch).
+                        retries + (sub_ovf & ~any_ovf).astype(jnp.int32),
+                    )
 
             carry0 = (
                 jnp.int32(0),
@@ -2300,8 +2300,6 @@ class XlaChecker(Checker):
             host_found = np.array(
                 [name in self._found_names for name in self._prop_names], dtype=bool
             )
-            f_in, e_in = self._bucket_inputs(run_cap)
-            fn = self._fused_for(run_cap)
             # Will THIS call trace + compile? The dispatch span and the
             # heartbeat phase carry the flag so watchdogs can tell a
             # long compile from a wedge.
@@ -2343,168 +2341,154 @@ class XlaChecker(Checker):
                 compile=fresh, retry=retry, dedup=self._dedup,
                 compaction=self._compaction, shrink_below=shrink_below,
             ) as _sp:
-                _pt0 = time.monotonic() if self._phases else 0.0
-                _args = (
-                    f_in,
-                    e_in,
-                    self._frontier_count,
-                    self._table,
-                    self._disc_found,
-                    self._disc_fp,
-                    jnp.int32(budget),
-                    jnp.int32(remaining),
-                    jnp.asarray(host_found),
-                    jnp.int32(shrink_below),
-                    jnp.int32(min(prev_gen, 2**31 - 1)),
-                    jnp.int32(min(prev2_gen, 2**31 - 1)),
-                )
-                _pt1 = time.monotonic() if self._phases else 0.0
-                (
-                    committed,
-                    nf,
-                    ne,
-                    ncount,
-                    table,
-                    dfound,
-                    dfp,
-                    tot_states,
-                    tot_unique,
-                    ovf,
-                    hv_w,
-                    hv_f,
-                    hv_c,
-                    lvl_frontier,
-                    lvl_states,
-                    lvl_unique,
-                    lvl_bucket,
-                    lvl_cand,
-                    _prev_gen,
-                    _prev2_gen,
-                    _force_full,
-                    n_retries,
-                ) = fn(*_args)
-                if self._phases:
-                    # fn() returned at enqueue; one output leaf becoming
-                    # ready means the one fused program finished — a wait
-                    # on work already in flight, not an added sync.
-                    _pt2 = time.monotonic()
-                    self._jax.block_until_ready(committed)
-                    _pt3 = time.monotonic()
-                # Commit the non-overflowing prefix of the block. The
-                # int() blocks until the device program finishes, so the
-                # span covers the whole round-trip — and reuses a sync
-                # the commit below needs anyway.
-                committed = int(committed)
+                with self._tracer.span("dispatch:prepare"):
+                    f_in, e_in = self._bucket_inputs(run_cap)
+                    fn = self._fused_for(run_cap)
+                    _args = (
+                        f_in,
+                        e_in,
+                        self._frontier_count,
+                        self._table,
+                        self._disc_found,
+                        self._disc_fp,
+                        jnp.int32(budget),
+                        jnp.int32(remaining),
+                        jnp.asarray(host_found),
+                        jnp.int32(shrink_below),
+                        jnp.int32(min(prev_gen, 2**31 - 1)),
+                        jnp.int32(min(prev2_gen, 2**31 - 1)),
+                    )
+                with self._tracer.span("dispatch:run"):
+                    (
+                        committed,
+                        nf,
+                        ne,
+                        ncount,
+                        table,
+                        dfound,
+                        dfp,
+                        tot_states,
+                        tot_unique,
+                        ovf,
+                        hv_w,
+                        hv_f,
+                        hv_c,
+                        lvl_frontier,
+                        lvl_states,
+                        lvl_unique,
+                        lvl_bucket,
+                        lvl_cand,
+                        _prev_gen,
+                        _prev2_gen,
+                        _force_full,
+                        n_retries,
+                    ) = fn(*_args)
+                    # Commit the non-overflowing prefix of the block. The
+                    # int() blocks until the device program finishes, so
+                    # this span covers the device run — and reuses a sync
+                    # the commit below needs anyway.
+                    committed = int(committed)
                 _sp.set(committed=committed)
-                _pt4 = time.monotonic() if self._phases else 0.0
-            self.dispatch_log.append((run_cap, committed))
-            if self._phases:
-                self._log_phases(
-                    _sp, flavor="fused", bucket=run_cap, fresh=fresh,
-                    committed=committed,
-                    stamps=(_pt0, _pt1, _pt2, _pt3, _pt4),
-                )
-            if self._heartbeat is not None:
-                self._heartbeat.commit(
-                    depth=self._depth + committed,
-                    states=self._state_count + int(tot_states),
-                )
-            retry = False
-            self._frontier, self._frontier_ebits, self._table = nf, ne, table
-            self._frontier_count = int(ncount)
-            self._disc_found, self._disc_fp = dfound, dfp
-            self._state_count += int(tot_states)
-            self._unique_count += int(tot_unique)
-            self.cand_retries += int(n_retries)
-            if committed:
-                lvf = np.asarray(lvl_frontier)
-                lvs = np.asarray(lvl_states)
-                lvu = np.asarray(lvl_unique)
-                lvb = np.asarray(lvl_bucket)
-                lvc = np.asarray(lvl_cand)
-                self.level_log.extend(
-                    {
-                        "depth": self._depth + i,
-                        "frontier": int(lvf[i]),
-                        "generated": int(lvs[i]),
-                        "unique": int(lvu[i]),
-                        "sym": self._sym_tag,
-                        # Dispatch-shape telemetry: the (rows, cand)
-                        # sub-widths this level actually ran at and the
-                        # cost-law lane-words they imply (the ladder A/B's
-                        # engine-measured evidence).
-                        "bucket": int(lvb[i]),
-                        "cand_cap": int(lvc[i]),
-                        "lane_words": self._level_lane_words(
-                            int(lvb[i]), int(lvc[i])
-                        ),
-                    }
-                    for i in range(committed)
-                )
-            self._depth += committed
-            if committed:
-                self._max_depth = max(self._max_depth, self._depth - 1)
-            budget_left -= committed
-            cap_before = self._table.capacity
-            self._grow_table_if_loaded()
-            grew_proactively = self._table.capacity > cap_before
-            if self._hv_idx:
-                self._confirm_hv_candidates(hv_w, hv_f, hv_c)
-            self._pin_found_names()
-            # Quiescent point: the committed prefix is fully reflected in
-            # host-visible state (even when this iteration ended on an
-            # overflow — the overflowing level was not committed).
-            self._maybe_checkpoint()
-            self._maybe_record()
-            if (
-                self._target_state_count is not None
-                and self._state_count >= self._target_state_count
-            ):
-                self._target_reached = True
-                return
-            t_ovf, f_ovf, c_ovf, cc_ovf = (bool(x) for x in np.asarray(ovf))
-            if c_ovf:
-                self._raise_codec_overflow()
-            if t_ovf:
-                # The proactive pass above may already have doubled past
-                # the blockage; only resolve again if it did not (every
-                # extra doubling is 2x memory AND a fresh shape compile).
-                if not grew_proactively:
-                    self._resolve_table_overflow()
-                retry = True
-                continue
-            if f_ovf:
-                run_cap = self._grow_frontier(run_cap)
-                retry = True
-                continue
-            if cc_ovf:
-                self._grow_cand_cap(run_cap)
-                retry = True
-                continue
-            if self._frontier_count == 0 or committed == 0:
-                break
-            if self._P > 0 and all(
-                name in self._found_names for name in self._prop_names
-            ):
-                break
-            # A shrink-exit (committed block, no overflow, live frontier
-            # at or below the threshold): drop to the snuggest compiled
-            # bucket that still has 4x expansion headroom.
-            if shrink_below and self._frontier_count <= shrink_below:
-                snug = [
-                    c
-                    for c in self._compiled_run_caps()
-                    if c < run_cap and self._frontier_count <= c // 4
-                ]
-                if snug:
-                    run_cap = min(snug)
-                    self._counters.inc("shrink_exits")
+                with self._tracer.span("dispatch:commit"):
+                    self.dispatch_log.append((run_cap, committed))
+                    if self._heartbeat is not None:
+                        self._heartbeat.commit(
+                            depth=self._depth + committed,
+                            states=self._state_count + int(tot_states),
+                        )
+                    retry = False
+                    self._frontier, self._frontier_ebits, self._table = nf, ne, table
+                    self._frontier_count = int(ncount)
+                    self._disc_found, self._disc_fp = dfound, dfp
+                    self._state_count += int(tot_states)
+                    self._unique_count += int(tot_unique)
+                    self.cand_retries += int(n_retries)
+                    if committed:
+                        lvf = np.asarray(lvl_frontier)
+                        lvs = np.asarray(lvl_states)
+                        lvu = np.asarray(lvl_unique)
+                        lvb = np.asarray(lvl_bucket)
+                        lvc = np.asarray(lvl_cand)
+                        self.level_log.extend(
+                            {
+                                "depth": self._depth + i,
+                                "frontier": int(lvf[i]),
+                                "generated": int(lvs[i]),
+                                "unique": int(lvu[i]),
+                                "sym": self._sym_tag,
+                                # Dispatch-shape telemetry: the (rows, cand)
+                                # sub-widths this level actually ran at and the
+                                # cost-law lane-words they imply (the ladder A/B's
+                                # engine-measured evidence).
+                                "bucket": int(lvb[i]),
+                                "cand_cap": int(lvc[i]),
+                                "lane_words": self._level_lane_words(
+                                    int(lvb[i]), int(lvc[i])
+                                ),
+                            }
+                            for i in range(committed)
+                        )
+                    self._depth += committed
+                    if committed:
+                        self._max_depth = max(self._max_depth, self._depth - 1)
+                    budget_left -= committed
+                    cap_before = self._table.capacity
+                    self._grow_table_if_loaded()
+                    grew_proactively = self._table.capacity > cap_before
+                    if self._hv_idx:
+                        self._confirm_hv_candidates(hv_w, hv_f, hv_c)
+                    self._pin_found_names()
+                    # Quiescent point: the committed prefix is fully reflected in
+                    # host-visible state (even when this iteration ended on an
+                    # overflow — the overflowing level was not committed).
+                    self._maybe_checkpoint()
+                    self._maybe_record()
+                    if (
+                        self._target_state_count is not None
+                        and self._state_count >= self._target_state_count
+                    ):
+                        self._target_reached = True
+                        return
+                    t_ovf, f_ovf, c_ovf, cc_ovf = (bool(x) for x in np.asarray(ovf))
+                    if c_ovf:
+                        self._raise_codec_overflow()
+                    if t_ovf:
+                        # The proactive pass above may already have doubled past
+                        # the blockage; only resolve again if it did not (every
+                        # extra doubling is 2x memory AND a fresh shape compile).
+                        if not grew_proactively:
+                            self._resolve_table_overflow()
+                        retry = True
+                        continue
+                    if f_ovf:
+                        run_cap = self._grow_frontier(run_cap)
+                        retry = True
+                        continue
+                    if cc_ovf:
+                        self._grow_cand_cap(run_cap)
+                        retry = True
+                        continue
+                    if self._frontier_count == 0 or committed == 0:
+                        break
+                    if self._P > 0 and all(
+                        name in self._found_names for name in self._prop_names
+                    ):
+                        break
+                    # A shrink-exit (committed block, no overflow, live frontier
+                    # at or below the threshold): drop to the snuggest compiled
+                    # bucket that still has 4x expansion headroom.
+                    if shrink_below and self._frontier_count <= shrink_below:
+                        snug = [
+                            c
+                            for c in self._compiled_run_caps()
+                            if c < run_cap and self._frontier_count <= c // 4
+                        ]
+                        if snug:
+                            run_cap = min(snug)
+                            self._counters.inc("shrink_exits")
 
     def _run_block_single(self) -> None:
         """One BFS level per call (level-synchronous super-step)."""
-        import jax
-        import jax.numpy as jnp
-
         if not self._entry_checks():
             return
 
@@ -2524,8 +2508,6 @@ class XlaChecker(Checker):
         run_cap = self._run_cap_for(self._frontier_count)
         retry = False  # re-running the level after an overflow recovery
         while True:  # retried only on capacity growth
-            f_in, e_in = self._bucket_inputs(run_cap)
-            fn = self._superstep_for(run_cap)
             fresh = self._mark_dispatch_shape(self._superstep_key(run_cap))
             if self._heartbeat is not None:
                 self._heartbeat.beat(
@@ -2538,133 +2520,97 @@ class XlaChecker(Checker):
                 retry=retry, dedup=self._dedup,
                 compaction=self._compaction,
             ) as _sp:
-                _pt0 = time.monotonic() if self._phases else 0.0
-                _args = (
-                    f_in,
-                    e_in,
-                    self._frontier_count,
-                    self._table,
-                    self._disc_found,
-                    self._disc_fp,
-                )
-                _pt1 = time.monotonic() if self._phases else 0.0
-                out = fn(*_args)
-                if self._phases:
-                    _pt2 = time.monotonic()
-                    self._jax.block_until_ready(out)
-                    _pt3 = time.monotonic()
-                (
-                    nf,
-                    ne,
-                    ncount,
-                    table,
-                    dfound,
-                    dfp,
-                    d_states,
-                    d_unique,
-                    t_ovf,
-                    f_ovf,
-                    c_ovf,
-                    cc_ovf,
-                    hv_words,
-                    hv_fps,
-                    hv_counts,
-                ) = out
-                # The bool() reads block until the device program
-                # finishes — the span covers the full round-trip using
-                # syncs the commit logic pays anyway.
-                committed = not (bool(t_ovf) or bool(f_ovf) or bool(cc_ovf))
+                with self._tracer.span("dispatch:prepare"):
+                    f_in, e_in = self._bucket_inputs(run_cap)
+                    fn = self._superstep_for(run_cap)
+                    _args = (
+                        f_in,
+                        e_in,
+                        self._frontier_count,
+                        self._table,
+                        self._disc_found,
+                        self._disc_fp,
+                    )
+                with self._tracer.span("dispatch:run"):
+                    (
+                        nf,
+                        ne,
+                        ncount,
+                        table,
+                        dfound,
+                        dfp,
+                        d_states,
+                        d_unique,
+                        t_ovf,
+                        f_ovf,
+                        c_ovf,
+                        cc_ovf,
+                        hv_words,
+                        hv_fps,
+                        hv_counts,
+                    ) = fn(*_args)
+                    # The bool() reads block until the device program
+                    # finishes — this span covers the device run using
+                    # syncs the commit logic pays anyway.
+                    committed = not (bool(t_ovf) or bool(f_ovf) or bool(cc_ovf))
                 _sp.set(committed=int(committed))
-                _pt4 = time.monotonic() if self._phases else 0.0
-            self.dispatch_log.append((run_cap, int(committed)))
-            if self._phases:
-                self._log_phases(
-                    _sp, flavor="single", bucket=run_cap, fresh=fresh,
-                    committed=int(committed),
-                    stamps=(_pt0, _pt1, _pt2, _pt3, _pt4),
-                )
-            if self._heartbeat is not None:
-                self._heartbeat.commit(
-                    depth=self._depth, states=self._state_count
-                )
-            if bool(c_ovf):
-                self._raise_codec_overflow()
-            if bool(t_ovf):
-                # Functional arrays: the pre-step table is untouched;
-                # flush (delta) or grow, then re-run the same level.
-                self._resolve_table_overflow()
-                retry = True
-                continue
-            if bool(f_ovf):
-                run_cap = self._grow_frontier(run_cap)
-                retry = True
-                continue
-            if bool(cc_ovf):
-                self._grow_cand_cap(run_cap)
-                retry = True
-                continue
-            break
-
-        self.level_log.append(
-            {
-                "depth": self._depth,
-                "frontier": self._frontier_count,
-                "generated": int(d_states),
-                "unique": int(d_unique),
-                "sym": self._sym_tag,
-                # The one-level path picks its snug bucket host-side, so
-                # its dispatch-shape telemetry is the run bucket itself
-                # (the in-program ladder applies to fused dispatch only).
-                "bucket": run_cap,
-                "cand_cap": self._cand_cap_for(run_cap),
-                "lane_words": self._level_lane_words(
-                    run_cap, self._cand_cap_for(run_cap)
-                ),
-            }
-        )
-        self._frontier, self._frontier_ebits, self._table = nf, ne, table
-        self._frontier_count = int(ncount)
-        self._disc_found, self._disc_fp = dfound, dfp
-        self._state_count += int(d_states)
-        self._unique_count += int(d_unique)
-        self._depth += 1
-        self._grow_table_if_loaded()
-        if self._hv_idx:
-            self._confirm_hv_candidates(hv_words, hv_fps, hv_counts)
-        self._pin_found_names()
-        self._maybe_checkpoint()
-        self._maybe_record()
-        if (
-            self._target_state_count is not None
-            and self._state_count >= self._target_state_count
-        ):
-            self._target_reached = True
-
-    #: Phase names in stamp order — the profiler's contiguous split of
-    #: one dispatch round-trip (docs/observability.md).
-    PHASE_NAMES = ("host_prep", "enqueue", "device_compute", "readback")
-
-    def _log_phases(
-        self, sp, *, flavor: str, bucket: int, fresh: bool,
-        committed: int, stamps: Tuple[float, ...],
-    ) -> None:
-        """Record one device call's phase split: a ``phase_log`` row
-        (dispatch_log-adjacent telemetry) plus four ``phase:*`` sub-spans
-        parented to the just-closed dispatch span. Called only with the
-        profiler on; the stamps are contiguous, so the phases partition
-        the dispatch span's interior exactly."""
-        row: Dict[str, Any] = {
-            "bucket": bucket, "flavor": flavor, "compile": fresh,
-            "committed": committed,
-        }
-        for i, name in enumerate(self.PHASE_NAMES):
-            dur = stamps[i + 1] - stamps[i]
-            row[name] = dur
-            self._tracer.emit(
-                f"phase:{name}", t0=stamps[i], dur=dur,
-                attrs={"bucket": bucket}, parent_id=sp.span_id,
-            )
-        self.phase_log.append(row)
+                with self._tracer.span("dispatch:commit"):
+                    self.dispatch_log.append((run_cap, int(committed)))
+                    if self._heartbeat is not None:
+                        self._heartbeat.commit(
+                            depth=self._depth, states=self._state_count
+                        )
+                    if bool(c_ovf):
+                        self._raise_codec_overflow()
+                    if bool(t_ovf):
+                        # Functional arrays: the pre-step table is untouched;
+                        # flush (delta) or grow, then re-run the same level.
+                        self._resolve_table_overflow()
+                        retry = True
+                        continue
+                    if bool(f_ovf):
+                        run_cap = self._grow_frontier(run_cap)
+                        retry = True
+                        continue
+                    if bool(cc_ovf):
+                        self._grow_cand_cap(run_cap)
+                        retry = True
+                        continue
+                    self.level_log.append(
+                        {
+                            "depth": self._depth,
+                            "frontier": self._frontier_count,
+                            "generated": int(d_states),
+                            "unique": int(d_unique),
+                            "sym": self._sym_tag,
+                            # The one-level path picks its snug bucket host-side, so
+                            # its dispatch-shape telemetry is the run bucket itself
+                            # (the in-program ladder applies to fused dispatch only).
+                            "bucket": run_cap,
+                            "cand_cap": self._cand_cap_for(run_cap),
+                            "lane_words": self._level_lane_words(
+                                run_cap, self._cand_cap_for(run_cap)
+                            ),
+                        }
+                    )
+                    self._frontier, self._frontier_ebits, self._table = nf, ne, table
+                    self._frontier_count = int(ncount)
+                    self._disc_found, self._disc_fp = dfound, dfp
+                    self._state_count += int(d_states)
+                    self._unique_count += int(d_unique)
+                    self._depth += 1
+                    self._grow_table_if_loaded()
+                    if self._hv_idx:
+                        self._confirm_hv_candidates(hv_words, hv_fps, hv_counts)
+                    self._pin_found_names()
+                    self._maybe_checkpoint()
+                    self._maybe_record()
+                    if (
+                        self._target_state_count is not None
+                        and self._state_count >= self._target_state_count
+                    ):
+                        self._target_reached = True
+                    break
 
     def _confirm_hv_candidates(self, hv_words, hv_fps, hv_counts) -> None:
         """Exact host-side re-check of device-flagged candidate states for

@@ -46,7 +46,6 @@ summed as ``lanes_active - 1`` per device call).
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Tuple
 
 import numpy as np
@@ -130,19 +129,10 @@ class MuxChecker:
         # their per-lane checkpoint/metrics hooks.
         self._tracer = lead._tracer
         self._heartbeat = lead._heartbeat
-        # Dispatch-phase profiler: inherited from the lead lane (one
-        # device call serves every lane, so the mux layer owns the split
-        # the same way it owns the dispatch span).
-        self._phases = lead._phases
-        #: One phase-split dict per device call (see XlaChecker.phase_log).
-        self.phase_log: List[Dict[str, Any]] = []
         #: One ``(run_cap, committed, lanes, lanes_active)`` per device
         #: call (the lane-axis extension of the engine's pinned 2-tuple).
         self.dispatch_log: List[Tuple[int, int, int, int]] = []
         self._dispatches_saved = 0
-
-    PHASE_NAMES = XlaChecker.PHASE_NAMES
-    _log_phases = XlaChecker._log_phases
 
     # --- program cache ----------------------------------------------------
 
@@ -354,29 +344,16 @@ class MuxChecker:
                 lanes=K, lanes_active=lanes_entry, compile=fresh,
                 retry=retry, dedup=lead._dedup, compaction=lead._compaction,
             ) as _sp:
-                _pt0 = time.monotonic() if self._phases else 0.0
                 args = self._stack(run_cap) + (
                     jnp.int32(budget),
                     jnp.asarray(remaining),
                     jnp.asarray(lane_budget),
                 )
-                _pt1 = time.monotonic() if self._phases else 0.0
                 (committed, nf, ne, ncount, table, dfound, dfp,
                  tot_s, tot_u, ovf, lv_act, lv_fr, lv_st, lv_un) = fn(*args)
-                if self._phases:
-                    _pt2 = time.monotonic()
-                    self._jax.block_until_ready(committed)
-                    _pt3 = time.monotonic()
                 committed = int(committed)
                 _sp.set(committed=committed)
-                _pt4 = time.monotonic() if self._phases else 0.0
             self.dispatch_log.append((run_cap, committed, K, lanes_entry))
-            if self._phases:
-                self._log_phases(
-                    _sp, flavor="mux", bucket=run_cap, fresh=fresh,
-                    committed=committed,
-                    stamps=(_pt0, _pt1, _pt2, _pt3, _pt4),
-                )
             self._dispatches_saved += max(0, lanes_entry - 1)
             retry = False
 

@@ -2,8 +2,9 @@
 span JSONL schema, Chrome trace-event export validity, the heartbeat
 protocol, the unified ``checker.metrics()`` snapshot, the normalized
 ``dispatch_log`` shape, the metrics time-series recorder (row schema,
-keep-K rotation, quiescent-boundary-only sampling), and the
-zero-overhead guarantee with tracing/recording off.
+keep-K rotation, quiescent-boundary-only sampling), the engine's spans
+and stage names on the ``jax.profiler`` trace, the compile-duration log,
+and the zero-overhead guarantee with tracing/recording off.
 
 These are SCHEMA pins: consumers (tools/roofline.py --measured, the
 bench watchdog, supervise.py, Perfetto, obs/promexport.py, the
@@ -11,8 +12,14 @@ bench watchdog, supervise.py, Perfetto, obs/promexport.py, the
 breaking change, not a refactor.
 """
 
+import glob
 import json
 import os
+import re
+import subprocess
+import sys
+import time
+from collections import Counter
 
 import pytest
 
@@ -226,53 +233,129 @@ def test_tracer_emit_overrides_and_preallocated_ids(tmp_path):
     assert "trace_id" not in recs["ambient"]  # no ambient ctx set
 
 
-# --- dispatch-phase profiler ----------------------------------------------
+# --- the profiler's clock (docs/observability.md "Profiler plane") ---------
+
+#: The superstep's named stages and the fused loop's own scope.
+STAGES = ("properties", "expand", "compact", "fingerprint", "insert",
+          "terminal", "ladder")
+
+#: One compiled-HLO instruction of the kinds that carry the superstep's
+#: work, with its op_name.
+HEAVY_RE = re.compile(
+    r'^\s*(?:ROOT )?%\S+ = .*?\b(?:fusion|sort|gather|scatter)\(.*'
+    r'op_name="([^"]*)"', re.M,
+)
 
 
-def test_phases_profiler_rows_and_spans(tmp_path):
-    """``spawn_xla(phases=True)``: every device call logs a phase_log
-    row whose host_prep/enqueue/device_compute/readback partition the
-    parent dispatch span, and emits ``phase:*`` sub-spans parented to
-    the dispatch span's id (tools/roofline.py --phases consumes both)."""
-    trace = str(tmp_path / "trace.jsonl")
-    c = _spawn(trace=trace, phases=True).join()
+def _fused_hlo(model):
+    """The compiled text of the planes engine's first fused program for a
+    fresh ``model`` (``dedup="sorted"`` selects the planes superstep the
+    chip runs; the CPU default is the rows engine)."""
+    c = model.checker().spawn_xla(dedup="sorted", **KW)
+    run_cap = c._run_cap_for(c._frontier_count)
+    fn = c._fused_for(run_cap)
+    calls = []
+
+    def spy(*args):
+        calls.append(args)
+        return fn(*args)
+
+    c._superstep_cache[c._fused_key(run_cap)] = spy
+    c.join()
+    return fn.lower(*calls[0]).compile().as_text()
+
+
+def _eventually_graph():
+    from stateright_tpu.core import Property
+    from stateright_tpu.test_util import DGraph, PackedDGraph
+
+    return PackedDGraph(
+        DGraph.with_property(Property.eventually("odd", lambda _, s: s % 2 == 1))
+        .with_path([0, 2, 4])
+        .with_path([0, 6, 8, 10])
+    )
+
+
+@pytest.mark.parametrize("case", ["2pc", "eventually"])
+def test_superstep_stages_named_in_compiled_program(case):
+    """Each stage of the planes superstep and the fused loop's own work
+    run under a ``jax.named_scope``: the names reach the compiled
+    program's op_name metadata, which a profiler trace carries on every
+    device operation. 2pc has no eventually property, so its terminal
+    pass compiles to nothing."""
+    if case == "2pc":
+        model, expect = PackedTwoPhaseSys(3), set(STAGES) - {"terminal"}
+    else:
+        model, expect = _eventually_graph(), set(STAGES)
+    hlo = _fused_hlo(model)
+    named = {p for n in re.findall(r'op_name="([^"]*)"', hlo)
+             for p in n.split("/") if p in STAGES}
+    assert expect <= named, expect - named
+    body = [n for n in HEAVY_RE.findall(hlo) if "/while/body/" in n]
+    assert body
+    staged = [n for n in body if set(n.split("/")) & set(STAGES)]
+    assert len(staged) > 0.9 * len(body), [n for n in body if n not in staged]
+
+
+def test_engine_spans_on_the_profiler_host_plane(tmp_path):
+    """Engine spans are ``jax.profiler`` annotations: a check under a
+    profiler session puts ``spawn`` and the three parts of each
+    ``dispatch`` on the trace's host plane, one ``dispatch`` per
+    ``dispatch_log`` entry."""
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        c = _spawn(levels_per_dispatch=4).join()
     assert c.unique_state_count() == 288
-    rows = c.phase_log
-    assert len(rows) == len(c.dispatch_log)
-    for row in rows:
-        assert {"bucket", "flavor", "compile", "committed"} <= set(row)
-        assert all(row[k] >= 0 for k in c.PHASE_NAMES)
-    lines = _spans(trace)
-    disp = {r["span_id"]: r for r in lines if r["name"] == "dispatch"}
-    phase = [r for r in lines if r["name"].startswith("phase:")]
-    assert len(phase) == len(rows) * len(c.PHASE_NAMES)
-    for rec in phase:
-        assert rec["parent_id"] in disp, rec
-    # Phases partition their dispatch: the four sub-spans sum to the
-    # parent's wall-clock minus only the inter-stamp bookkeeping.
-    by_parent = {}
-    for rec in phase:
-        by_parent.setdefault(rec["parent_id"], 0.0)
-        by_parent[rec["parent_id"]] += rec["dur"]
-    for sid, total in by_parent.items():
-        assert 0.0 <= disp[sid]["dur"] - total < 0.05, (sid, total)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = Counter(
+        e.name
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    )
+    assert names["spawn"] == names["spawn:init_insert"] == 1
+    assert names["dispatch"] == len(c.dispatch_log) > 1
+    for part in ("dispatch:prepare", "dispatch:run", "dispatch:commit"):
+        assert names[part] == names["dispatch"], part
 
 
-def test_phases_env_knob(tmp_path, monkeypatch):
-    monkeypatch.setenv("STPU_TRACE", str(tmp_path / "t.jsonl"))
-    monkeypatch.setenv("STPU_PHASES", "1")
-    c = _spawn().join()
-    assert c._phases and len(c.phase_log) == len(c.dispatch_log)
-    with pytest.raises(ValueError, match="STPU_PHASES"):
-        monkeypatch.setenv("STPU_PHASES", "maybe")
-        _spawn()
+def test_compile_log_records_lowering_with_end_times():
+    """``obs.compile_log``: JAX's tracing and lowering durations of a fresh
+    model's programs, each with its end on ``time.monotonic``; entries of
+    one kind never overlap (nested traces fold into the outer one)."""
+    t0 = time.monotonic()
+    PackedTwoPhaseSys(3).checker().spawn_xla(**KW).join()
+    t1 = time.monotonic()
+    fresh = [e for e in obs.compile_log if e[1] >= t0]
+    assert {"jaxpr_trace_duration", "jaxpr_to_mlir_module_duration"} <= {
+        name for name, _, _ in fresh
+    }
+    for name, end, secs in fresh:
+        assert name in obs.compiles.EVENTS
+        assert t0 <= end <= t1 and 0 <= secs <= t1 - t0
+    for kind in obs.compiles.EVENTS:
+        spans = sorted((end - secs, end) for name, end, secs in fresh if name == kind)
+        for (_, end), (start, _) in zip(spans, spans[1:]):
+            assert start >= end - 1e-6, kind
 
 
-def test_phases_require_tracer():
-    """phases=True without a trace sink is inert (nowhere to emit the
-    sub-spans), not an error — the flag gates on tracer.enabled."""
-    c = _spawn(phases=True).join()
-    assert not c._phases and c.phase_log == []
+def test_obs_imports_without_jax():
+    """``obs`` and ``supervise`` import, and spans open, with jax absent
+    (the supervisor process stays free of it)."""
+    code = (
+        "import sys; sys.modules['jax'] = None\n"
+        "import stateright_tpu.obs as obs, stateright_tpu.supervise\n"
+        "with obs.NULL_TRACER.span('spawn'): pass\n"
+        "print(len(obs.compile_log))\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    p = subprocess.run([sys.executable, "-c", code], cwd=root,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+    assert p.stdout.strip() == "0"
 
 
 # --- heartbeat ------------------------------------------------------------
@@ -586,20 +669,44 @@ def test_tracing_off_is_nulled_and_bit_identical(tmp_path):
     assert off._tracer is NULL_TRACER
     assert off._heartbeat is None
     assert off._recorder is None
-    # The dispatch-phase profiler shares the pin: off by default, no
-    # clock stamps, no rows.
-    assert off._phases is False and off.phase_log == []
 
     trace = str(tmp_path / "trace.jsonl")
     hb = str(tmp_path / "hb.json")
     on = _spawn(
-        trace=trace, heartbeat=hb, phases=True,
+        trace=trace, heartbeat=hb,
         metrics_to=str(tmp_path / "metrics.jsonl"), metrics_every=1,
     ).join()
-    assert len(on.phase_log) == len(on.dispatch_log) > 0
     # Engine results are bit-identical with tracing on: same counts, same
     # schedule, same per-level telemetry (spans only *observe* host
     # boundaries; they never change what runs on the device).
+    assert (off.state_count(), off.unique_state_count(), off.max_depth()) == (
+        on.state_count(), on.unique_state_count(), on.max_depth(),
+    )
+    assert off.level_log == on.level_log
+    assert off.dispatch_log == on.dispatch_log
+    assert {n: p.into_actions() for n, p in off.discoveries().items()} == {
+        n: p.into_actions() for n, p in on.discoveries().items()
+    }
+
+
+def test_no_profiler_no_trace_writes_nothing(tmp_path, monkeypatch):
+    """With no profiler session and no ``STPU_TRACE`` the spans are bare
+    annotations: the run writes no file, and its results are
+    bit-identical to a run whose spans a profiler session records."""
+    import jax
+    from stateright_tpu.obs.trace import NULL_TRACER
+
+    for var in ("STPU_TRACE", "STPU_TRACE_CHROME", "STPU_HEARTBEAT",
+                "STPU_METRICS_TO"):
+        monkeypatch.delenv(var, raising=False)
+    quiet = tmp_path / "quiet"
+    quiet.mkdir()
+    monkeypatch.chdir(quiet)
+    off = _spawn().join()
+    assert off._tracer is NULL_TRACER
+    assert list(quiet.iterdir()) == []
+    with jax.profiler.trace(str(tmp_path / "profile")):
+        on = _spawn().join()
     assert (off.state_count(), off.unique_state_count(), off.max_depth()) == (
         on.state_count(), on.unique_state_count(), on.max_depth(),
     )

@@ -37,17 +37,6 @@ Three modes:
     a run dir's discovered traces, then the detail file's recorded
     ``trace``, then a metrics series (coarse run-level rates only).
 
-``python tools/roofline.py --phases [trace.jsonl | run_dir]``
-    The dispatch-phase profiler report (``spawn_xla(phases=True)`` /
-    ``STPU_PHASES=1`` — docs/observability.md "Distributed tracing"):
-    aggregates the ``phase:*`` sub-spans under each dispatch into
-    host_prep / enqueue / device_compute / readback totals, split
-    steady-state vs compile-carrying, with per-bucket rows. Reports the
-    measured host-RTT share, device occupancy, and the projected
-    pipelined throughput — the wall-clock the same schedule would take
-    if host phases overlapped device compute (the pipelining attack's
-    headroom: ``max(Σhost, Σdevice)`` vs their sum today).
-
 ``python tools/roofline.py --model [runs/bench_detail.json]``
     The DESIGN's traffic-bound ceiling on v5e-1 (VERDICT r4 item 3): for
     each committed level of the recorded schedule, the minimum HBM bytes
@@ -228,7 +217,9 @@ def measured_stages(trace_path: str) -> dict:
                         base_unix = u
                     offset = u - base_unix
                 continue
-            if name is None:
+            if name is None or ":" in name:
+                # ``dispatch:run`` etc. are sub-spans of a span counted
+                # here already.
                 continue
             attrs = rec.get("attrs", {})
             if name == "dispatch":
@@ -340,159 +331,6 @@ def measured_stages_multi(trace_paths: list) -> dict:
         "instrumented_sec": round(total, 4),
         "trace_span_sec": round(wall, 4),
     }
-
-
-#: The dispatch-phase profiler's sub-span names, in pipeline order
-#: (mirrors XlaChecker.PHASE_NAMES — host_prep/enqueue run on the host
-#: before the device, readback after; enqueue carries XLA compile time
-#: on fresh programs, which is why compile-carrying dispatches report
-#: separately below).
-PHASE_NAMES = ("host_prep", "enqueue", "device_compute", "readback")
-HOST_PHASES = ("host_prep", "enqueue", "readback")
-
-
-def phase_report(trace_paths: list) -> dict:
-    """Aggregates ``phase:*`` sub-spans (the dispatch-phase profiler,
-    ``spawn_xla(phases=True)``/``STPU_PHASES=1``) across one or more
-    traces into the pipelining-attack report: per-phase seconds split
-    steady vs compile-carrying, per-bucket rows, host-RTT share, device
-    occupancy, and the projected pipelined wall-clock — what the same
-    steady-state schedule would cost if host phases overlapped device
-    compute (``max(Σhost, Σdevice)``)."""
-    # Pass 1 accumulates dispatch parents; phase spans are emitted after
-    # their parent dispatch span in every tracer session, but keep the
-    # two-pass shape so multi-file ordering never matters.
-    parents = {}  # span_id -> {"compile": bool, "bucket": int}
-    phase_rows = []  # (phase, dur, parent_id, fallback_bucket)
-    for path in trace_paths:
-        with open(path) as fh:
-            for line in fh:
-                try:
-                    rec = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                name = rec.get("name")
-                if name == "dispatch" and rec.get("span_id"):
-                    attrs = rec.get("attrs", {})
-                    parents[rec["span_id"]] = {
-                        "compile": bool(attrs.get("compile")),
-                        "bucket": attrs.get("bucket"),
-                    }
-                elif isinstance(name, str) and name.startswith("phase:"):
-                    attrs = rec.get("attrs", {})
-                    phase_rows.append((
-                        name[len("phase:"):], rec.get("dur", 0.0),
-                        rec.get("parent_id"), attrs.get("bucket"),
-                    ))
-    if not phase_rows:
-        return {"dispatches": 0, "phases": {}}
-    zero = lambda: {k: 0.0 for k in PHASE_NAMES}  # noqa: E731
-    steady, compile_ = zero(), zero()
-    by_bucket = {}
-    dispatches = set()
-    for phase, dur, parent, bucket in phase_rows:
-        if phase not in steady:
-            continue
-        par = parents.get(parent, {})
-        is_compile = par.get("compile", False)
-        bucket = par.get("bucket", bucket)
-        (compile_ if is_compile else steady)[phase] += dur
-        if parent is not None:
-            dispatches.add(parent)
-        if not is_compile:
-            row = by_bucket.setdefault(bucket, zero())
-            row[phase] += dur
-    s_host = sum(steady[k] for k in HOST_PHASES)
-    s_dev = steady["device_compute"]
-    s_total = s_host + s_dev
-    pipelined = max(s_host, s_dev)
-    out = {
-        "dispatches": len(dispatches) or len(phase_rows) // len(PHASE_NAMES),
-        "phases": {
-            "steady": {k: round(v, 4) for k, v in steady.items()},
-            "compile_carrying": {k: round(v, 4) for k, v in compile_.items()},
-        },
-        "by_bucket": {
-            str(b): {k: round(v, 4) for k, v in row.items()}
-            for b, row in sorted(
-                by_bucket.items(), key=lambda kv: (kv[0] is None, kv[0])
-            )
-        },
-        "steady_sec": round(s_total, 4),
-        "host_share": round(s_host / max(s_total, 1e-12), 3),
-        "device_occupancy": round(s_dev / max(s_total, 1e-12), 3),
-        "projected_pipelined_sec": round(pipelined, 4),
-        "pipeline_speedup": round(s_total / max(pipelined, 1e-12), 2),
-    }
-    return out
-
-
-def _phases_main(args: list) -> None:
-    """``--phases``: the dispatch-phase profiler report. Args may be a
-    span trace, a run dir (traces auto-discovered), and/or a detail
-    JSON (contributes the generated count for projected throughput);
-    with none, the default detail file's recorded trace is used."""
-    detail = detail_path = None
-    traces = []
-    for a in args:
-        if os.path.isdir(a):
-            traces.extend(discover_traces(a))
-        elif a.endswith(".jsonl"):
-            traces.append(a)
-        else:
-            with open(a) as fh:
-                detail = json.load(fh)
-            detail_path = a
-    if detail is None:
-        detail, detail_path = _load_default_detail()
-    if not traces and detail is not None:
-        t = detail.get("trace")
-        if t and os.path.exists(t):
-            traces = [t]
-    if not traces:
-        print(
-            "no trace: run with STPU_TRACE=path STPU_PHASES=1 (or "
-            "spawn_xla(trace=..., phases=True)), then pass the trace or "
-            "its run dir to tools/roofline.py --phases"
-        )
-        sys.exit(1)
-    out = phase_report(traces)
-    out["trace"] = traces if len(traces) > 1 else traces[0]
-    if not out["dispatches"]:
-        print(json.dumps(out, indent=1))
-        print(
-            "# trace has no phase:* sub-spans — the profiler is off by "
-            "default; rerun with STPU_PHASES=1 (needs STPU_TRACE too)"
-        )
-        sys.exit(1)
-    gen = None
-    if detail is not None:
-        out["detail"] = detail_path
-        gen = sum(int(lv.get("generated", 0)) for lv in _levels(detail))
-    if gen:
-        out["measured_gen_per_s"] = round(gen / max(out["steady_sec"], 1e-12), 0)
-        out["projected_pipelined_gen_per_s"] = round(
-            gen / max(out["projected_pipelined_sec"], 1e-12), 0
-        )
-    print(json.dumps(out, indent=1))
-    st = out["phases"]["steady"]
-    print(
-        f"# {out['dispatches']} profiled dispatches, steady phases: "
-        f"host_prep {st['host_prep']:.3f}s + enqueue {st['enqueue']:.3f}s + "
-        f"readback {st['readback']:.3f}s (host) vs device_compute "
-        f"{st['device_compute']:.3f}s -> host share {out['host_share']:.0%}, "
-        f"device occupancy {out['device_occupancy']:.0%}"
-    )
-    tail = (
-        f" ({out.get('measured_gen_per_s', 0)/1e6:.2f} -> "
-        f"{out.get('projected_pipelined_gen_per_s', 0)/1e6:.2f} M gen/s)"
-        if gen else ""
-    )
-    print(
-        f"# pipelining attack headroom: overlapped host/device wall "
-        f"{out['projected_pipelined_sec']:.3f}s vs {out['steady_sec']:.3f}s "
-        f"serial today = {out['pipeline_speedup']:.2f}x{tail}"
-    )
 
 
 def _jsonl_kind(path: str) -> str | None:
@@ -689,9 +527,6 @@ def _measured_main(args: list) -> None:
 
 def main() -> None:
     args = [a for a in sys.argv[1:] if not a.startswith("--")]
-    if "--phases" in sys.argv:
-        _phases_main(args)
-        return
     if "--measured" in sys.argv:
         _measured_main(args)
         return

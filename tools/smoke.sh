@@ -22,10 +22,10 @@
 # co-queued same-spec jobs as ONE worker.py --mux invocation — exact
 # pinned counts per member, per-lane mux provenance, pool gauges,
 # journaled mux_group starts — the batched-scheduling tier's tier-0
-# proof), and the <30s TRACE MERGE drill (a phases-profiled packed model
+# proof), and the <30s TRACE MERGE drill (a traced packed model
 # plus a traced 2-job service round merge via obs/collect.py into one
 # Chrome trace: schema valid, monotonic timeline, flow arrows resolve,
-# phases partition their dispatch — the distributed-tracing tier's
+# dispatch:* sub-spans nest in their dispatch — the distributed-tracing tier's
 # tier-0 proof), and the <30s QOS SHED drill (class-aware admission on a
 # saturated pool: best_effort sheds first with a measured Retry-After,
 # batch sheds at its own threshold, interactive admits until the hard
