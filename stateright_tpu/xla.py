@@ -128,6 +128,23 @@ def accel_auto_compaction(state_words: int) -> str:
     return "gather" if state_words > 8 else "sort"
 
 
+#: The sort compaction recovers candidate parents by merge (two sorts and
+#: a prefix sum, ``parents_by_merge``) where it compacts at least this many
+#: candidate lanes, and by three gathers below. On a TPU v5e a gathered
+#: element costs 8-23 ns and a sorted lane-operand about 1 ns, but each
+#: sort and scan compiles to megabytes of code for its shape, and that code
+#: stays resident in device memory beside the data (PERF.md §6, PR 24).
+#: Below 2^20 lanes the gathers cost under about 18 ms a level.
+PARENT_MERGE_MIN = 1 << 20
+
+
+def parent_lowering(compaction: str, f_cap: int, cand_cap: int, max_actions: int) -> str:
+    """How the plane-major grid compaction at these shapes recovers each
+    candidate's parent fingerprint and ebits: "merge" or "gather"."""
+    take = min(cand_cap, f_cap * max_actions)
+    return "merge" if compaction == "sort" and take >= PARENT_MERGE_MIN else "gather"
+
+
 # --- the ladder/rung planner, as shared pure functions ----------------------
 #
 # The compile-shape schedule — which run buckets the ladder can land on,
@@ -408,18 +425,21 @@ class XlaChecker(Checker):
             self._soa = dedup != "hash"
             # Planes-compaction lowering: "gather" computes the permutation
             # once (one small sort) and gathers every plane by it; "sort"
-            # carries the planes as sort payload operands — no random gathers,
-            # more sorted bytes; "bsearch" replaces the permutation sort with
-            # cumsum + rank binary-search + ascending gathers. The round-5
-            # on-chip A/Bs settled the hardware question per shape class:
+            # carries the state planes as sort payload operands and, from
+            # PARENT_MERGE_MIN candidate lanes, recovers the parents by
+            # merge rather than by gather; "bsearch" replaces the
+            # permutation sort with cumsum + rank binary-search + ascending
+            # gathers. The round-5 on-chip A/Bs settled the hardware
+            # question per shape class:
             #   - narrow-W (2pc W=2, rm=8): sort 8.8s vs gather 15.6s vs
-            #     bsearch 29.0s measured — random gathers at table scale are
-            #     the dominant per-level cost and sort payload wins;
-            #   - wide-W (paxos W=25): the sort-mode grid compaction becomes a
-            #     W+3 = 28-operand lax.sort whose XLA:TPU *compile* stalls for
-            #     tens of minutes (two bench workers in a row), while gather
-            #     compiles in ~2 min and measures fastest (3.2s vs bsearch
-            #     4.6s);
+            #     bsearch 29.0s measured — random gathers are the dominant
+            #     per-level cost (10-20x a sorted lane-operand per element
+            #     on a TPU v5e, PERF.md §5) and sorting wins;
+            #   - wide-W (paxos W=25): the sort-mode grid compaction becomes
+            #     a lax.sort of 1+W operands or more, whose XLA:TPU
+            #     *compile* stalled for tens of minutes (two bench workers
+            #     in a row), while gather compiles in ~2 min and measures
+            #     fastest (3.2s vs bsearch 4.6s);
             #   - 1-core CPU: gather wins everywhere (round-3 model).
             # So "auto" resolves per backend AND per model width: sort-family
             # compaction only where its operand count stays small.
@@ -1301,14 +1321,18 @@ class XlaChecker(Checker):
                 # ("bsearch" with a prio falls back to the sort lowering —
                 # the engine's bsearch grid build emits state-major order,
                 # so no prio path stays hot under it; "pallas" lands here
-                # for shapes below its kernel block.)
+                # for shapes below its kernel block.) Without a prio the
+                # lane index orders each class, so every key is unique and
+                # the sort need not be stable: XLA:TPU implements a stable
+                # sort with one more operand, an iota.
+                if prio is None:
+                    iota = jnp.arange(m, dtype=jnp.int32)
+                    key = jnp.where(mask, iota, iota + jnp.int32(1 << 30))
                 sorted_all = jax.lax.sort(
-                    (key, *lanes), num_keys=1, is_stable=True
+                    (key, *lanes), num_keys=1, is_stable=False
                 )
                 skey = sorted_all[0][:take]
-                smask = (
-                    skey == 0 if prio is None else skey < jnp.int32(1 << 30)
-                )
+                smask = skey < jnp.int32(1 << 30)
                 slanes = [s[:take] for s in sorted_all[1:]]
             else:
                 iota = jnp.arange(m, dtype=jnp.int32)
@@ -1358,6 +1382,63 @@ class XlaChecker(Checker):
             return outs, n_valid
 
         eval_properties, terminal_pass = self._checking_blocks()
+        has_ebits = bool(self._ebit_of_prop)
+        merge_parents = parent_lowering(compaction, f_cap, cand_cap, A) == "merge"
+
+        def parents_by_merge(skey, fhi, flo, f_ebits):
+            """Each sorted grid candidate's parent fingerprint and ebits,
+            without a gather. ``skey`` holds the candidates' grid keys in
+            ascending order (the state-major rank f*A + a; invalid ones
+            2^30 above, after every valid one). One marker lane per
+            frontier row f, keyed ``(f*A) << 1``, merges in just before its
+            row's candidates, keyed ``(skey << 1) | 1``. Markers carry the
+            wrapping first differences of the row arrays and candidates
+            carry 0, so a uint32 prefix sum over the merged order hands
+            every candidate its parent row's values exactly (the sum
+            telescopes mod 2^32). The fill also re-keys the lanes, and a
+            second pass of the same sort brings the candidates back to the
+            front in their order. Keys are unique in both passes, so the
+            sort need not be stable. The two passes run one sort in a
+            two-trip loop: each sort instance compiles to megabytes of code
+            for its shape, resident in device memory. Without eventually
+            properties the ebits are all zero and are not carried."""
+            u32 = jnp.uint32
+            n = skey.shape[0]
+            rows = [fhi, flo] + ([f_ebits] if has_ebits else [])
+            zeros = jnp.zeros((n,), u32)
+            key = jnp.concatenate([
+                (skey.astype(u32) << u32(1)) | u32(1),
+                (jnp.arange(f_cap, dtype=u32) * u32(A)) << u32(1),
+            ])
+            deltas = [r - jnp.concatenate([zeros[:1], r[:-1]]) for r in rows]
+
+            def prefix_sum(v):
+                # jnp.cumsum lowers for a TPU to this same reduce-window,
+                # but outside the enclosing named scope.
+                return jax.lax.platform_dependent(
+                    v,
+                    tpu=lambda v: jax.lax.reduce_window(
+                        v, u32(0), jax.lax.add, (v.shape[0],), (1,),
+                        [(v.shape[0] - 1, 0)],
+                    ),
+                    default=lambda v: jnp.cumsum(v, dtype=u32),
+                )
+
+            def fill(key, *vals):
+                # Candidates (odd keys) first, in key order; markers last.
+                back_key = (key >> u32(1)) | ((~key & u32(1)) << u32(31))
+                return (back_key, *map(prefix_sum, vals))
+
+            def sort_pass(i, lanes):
+                lanes = jax.lax.sort(lanes, num_keys=1, is_stable=False)
+                return jax.lax.cond(i == 0, fill, lambda *ls: ls, *lanes)
+
+            _, *out = jax.lax.fori_loop(
+                0, 2, sort_pass,
+                (key, *[jnp.concatenate([zeros, d]) for d in deltas]),
+            )
+            out = [v[:n] for v in out]
+            return out if has_ebits else [*out, zeros]
 
         def hv_compact_planes(frontier, fhi, flo):
             def hv_compact(viol):
@@ -1440,25 +1521,25 @@ class XlaChecker(Checker):
                     prio = (j % f_cap) * A + (j // f_cap)  # semantic rank f*A + a
                 if compaction == "sort":
                     # The grid sort is the engine's largest per-level op (A*F
-                    # lanes; ~60% of the sorted lane-words at rm=8 shapes), and
-                    # the parent-fp/ebits payloads are pure functions of the
-                    # winning priority key (state-major rank k -> parent row
-                    # k // A) — so sort ONLY key + state planes and recover
-                    # parents/ebits by [cand_cap]-sized gathers from the
-                    # [F]-sized frontier arrays afterwards. Bit-identical to
-                    # carrying them as payload; removes 3 of the W+4 operands
-                    # from the dominant sort.
+                    # lanes). It carries only the key and the W state planes
+                    # (its keys are unique, so it need not be stable): the
+                    # parent fingerprints and ebits are functions of the
+                    # winning key (state-major rank k -> parent row k // A),
+                    # recovered afterwards at candidate scale. Carrying them
+                    # as grid payload would make the grid sort the program's
+                    # memory high-water mark (+29% temp at rm=8's full rung);
+                    # gathering them by row costs 10-20x a sorted
+                    # lane-operand per element on a TPU v5e, so wide buffers
+                    # recover them by merge (PARENT_MERGE_MIN).
                     m_grid = A * f_cap
                     gkey = jnp.where(vmask, prio, prio + jnp.int32(1 << 30))
                     take = min(cand_cap, m_grid)
                     sorted_all = jax.lax.sort(
                         (gkey, *[grid[w] for w in range(W)]),
-                        num_keys=1, is_stable=True,
+                        num_keys=1, is_stable=False,
                     )
                     skey = sorted_all[0][:take]
                     smask = skey < jnp.int32(1 << 30)
-                    k_rank = (skey & jnp.int32((1 << 30) - 1)) // jnp.int32(A)
-                    f_row = jnp.clip(k_rank, 0, f_cap - 1)
                     z32 = jnp.uint32(0)
 
                     def pad_lane(lane):
@@ -1472,9 +1553,15 @@ class XlaChecker(Checker):
                     ccand = jnp.stack(
                         [pad_lane(s[:take]) for s in sorted_all[1:]]
                     )
-                    cpar_hi = pad_lane(fhi[f_row])
-                    cpar_lo = pad_lane(flo[f_row])
-                    cebits = pad_lane(f_ebits[f_row])
+                    if merge_parents:
+                        parents = parents_by_merge(skey, fhi, flo, f_ebits)
+                    else:
+                        f_row = jnp.clip(
+                            (skey & jnp.int32((1 << 30) - 1)) // jnp.int32(A),
+                            0, f_cap - 1,
+                        )
+                        parents = (fhi[f_row], flo[f_row], f_ebits[f_row])
+                    cpar_hi, cpar_lo, cebits = map(pad_lane, parents)
                     n_valid = jnp.sum(vmask, dtype=jnp.int32)
                 else:
                     (ccand, cpar_hi, cpar_lo, cebits), n_valid = compact_1d(
@@ -1854,6 +1941,14 @@ class XlaChecker(Checker):
             run_cap, self._A, self._jax.default_backend()
         )
 
+    def _parent_lowering(self, run_cap: int, cand_cap: Optional[int] = None) -> str:
+        """:func:`parent_lowering` of this engine's superstep at a bucket
+        (by default at the bucket's current candidate cap; a pure read)."""
+        if cand_cap is None:
+            cand_cap = self._cand_caps.get(run_cap, self._default_cand_cap(run_cap))
+        compaction = self._compaction if self._soa else "rows"
+        return parent_lowering(compaction, run_cap, cand_cap, self._A)
+
     @staticmethod
     def _next_pow2(n: int) -> int:
         return _next_pow2(n)
@@ -1937,9 +2032,12 @@ class XlaChecker(Checker):
         """32-bit words carried through ``lax.sort`` operands by ONE
         committed level at these dispatch shapes — the x-axis of the
         round-5 cost law (per-level time ~ sorted lane-words x log^2 n,
-        tools/roofline.py). Computed from the actual static sort shapes the
-        compiled program runs (grid compaction + visited-set insert +
-        frontier compaction at engine scale; the hv_cap- and
+        tools/roofline.py). The law covers sorts only: on a TPU v5e a
+        gathered element costs 10-20x a sorted lane-operand (PERF.md §5),
+        and gathers are not counted here. Computed from the actual static
+        sort shapes the compiled program runs (grid compaction, parent
+        recovery, visited-set insert and frontier compaction at engine
+        scale; the hv_cap- and
         symmetry-only side sorts are bounded and not counted), so the
         candidate-ladder A/B is engine-measured, not hand-derived. The
         rows/hash engine sorts nothing (cumsum + scatter compaction)."""
@@ -1949,8 +2047,13 @@ class XlaChecker(Checker):
         grid = bucket * self._A
         total = 0
         if self._compaction == "sort":
-            # Grid: key + W state planes; frontier: key + W rows + ebits.
+            # Grid: key + W state planes; frontier: key + W rows + ebits;
+            # a merge recovery: two passes of key + parent fingerprint
+            # (+ ebits) over candidates and frontier rows.
             total += grid * (1 + W) + cand_w * (2 + W)
+            if self._parent_lowering(bucket, cand_w) == "merge":
+                lanes = 3 + bool(self._ebit_of_prop)
+                total += 2 * lanes * (min(cand_w, grid) + bucket)
         elif self._compaction == "gather":
             # Permutation sorts only (key + iota); payloads move by gather.
             total += grid * 2 + cand_w * 2
@@ -2731,6 +2834,9 @@ class XlaChecker(Checker):
             # -- configuration gauges ---------------------------------
             "dedup": self._dedup,
             "compaction": self._compaction,
+            # How the widest bucket's grid compaction recovers each
+            # candidate's parent fingerprint and ebits (parent_lowering).
+            "parent_lowering": self._parent_lowering(self._frontier_capacity),
             "symmetry": self._sym_tag,
             "ladder": self._ladder,
             "cand_ladder_k": self._cand_ladder_k,

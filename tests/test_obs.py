@@ -247,11 +247,11 @@ HEAVY_RE = re.compile(
 )
 
 
-def _fused_hlo(model):
+def _fused_hlo(model, **kw):
     """The compiled text of the planes engine's first fused program for a
     fresh ``model`` (``dedup="sorted"`` selects the planes superstep the
     chip runs; the CPU default is the rows engine)."""
-    c = model.checker().spawn_xla(dedup="sorted", **KW)
+    c = model.checker().spawn_xla(dedup="sorted", **KW, **kw)
     run_cap = c._run_cap_for(c._frontier_count)
     fn = c._fused_for(run_cap)
     calls = []
@@ -295,6 +295,23 @@ def test_superstep_stages_named_in_compiled_program(case):
     assert body
     staged = [n for n in body if set(n.split("/")) & set(STAGES)]
     assert len(staged) > 0.9 * len(body), [n for n in body if n not in staged]
+
+
+@pytest.mark.parametrize("compaction", ["sort", "gather"])
+def test_sort_compaction_recovers_parents_without_gathers(compaction, monkeypatch):
+    """Where the sort lowering of the grid compaction recovers candidate
+    parents by merge (here at every width), no ``gather`` in the compiled
+    program carries the ``compact`` scope. The gather lowering, which
+    indexes by the permutation, shows that the pattern finds them."""
+    from stateright_tpu import xla
+
+    monkeypatch.setattr(xla, "PARENT_MERGE_MIN", 1)
+    hlo = _fused_hlo(PackedTwoPhaseSys(3), compaction=compaction)
+    gathers = [
+        n for n in re.findall(r'\bgather\(.*op_name="([^"]*)"', hlo)
+        if "compact" in n.split("/")
+    ]
+    assert bool(gathers) == (compaction == "gather"), gathers
 
 
 def test_engine_spans_on_the_profiler_host_plane(tmp_path):

@@ -99,17 +99,24 @@ def test_pallas_compaction_compiles(one_chip):
 
 
 @pytest.mark.parametrize("compaction", ["sort", "gather"])
-def test_packed_superstep_compiles(one_chip, accel_branches, compaction):
+def test_packed_superstep_compiles(one_chip, accel_branches, compaction, monkeypatch):
     """2pc rm=3's fused superstep on the accelerator's engine: sorted
-    visited set, plane-major buffers and the chosen plane compaction."""
+    visited set, plane-major buffers and the chosen plane compaction. The
+    sort compaction recovers candidate parents by merge, here at every
+    width."""
+    from stateright_tpu import xla
     from stateright_tpu.models.two_phase_commit import PackedTwoPhaseSys
 
+    monkeypatch.setattr(xla, "PARENT_MERGE_MIN", 1)
     checker = PackedTwoPhaseSys(3).checker().spawn_xla(
         frontier_capacity=1 << 8,
         table_capacity=1 << 10,
         compaction=compaction,
     )
     assert (checker._dedup, checker._soa) == ("sorted", True)
+    assert checker.metrics()["parent_lowering"] == (
+        "merge" if compaction == "sort" else "gather"
+    )
     cap = 1 << 8
     f_in, e_in = checker._bucket_inputs(cap)
     args = (
