@@ -14,6 +14,8 @@ appear unwrapped in ``actor_states``.
 
 from __future__ import annotations
 
+import functools
+
 from ..semantics import HistoryError
 from ..semantics.register import Read as RegisterRead
 from ..semantics.register import ReadOk as RegisterReadOk
@@ -272,6 +274,17 @@ class PackedClientsMixin:
         dup = L.get(w, "net", idx) != 0
         return L.set(w, "net", 1, idx), dup
 
+    @functools.cached_property
+    def property_block_rows(self) -> int:
+        """Rows per block of the engine's property stage: the device
+        serializer's row-block width for this history's threads, op bound
+        and pattern limit (``semantics.device.block_rows``). The engine
+        evaluates ``packed_properties`` in blocks of this many live rows
+        wherever its bucket is wider (``xla.blocked_properties``)."""
+        from ..semantics.device import block_rows
+
+        return block_rows(self._hist, getattr(self, "_pattern_limit", None))
+
     def device_linearizable_register(self, words, pattern_limit=None):
         """EXACT linearizability of the packed history, entirely on device —
         no host fallback (SURVEY §7 M4 variant (b), upgrading the
@@ -280,8 +293,8 @@ class PackedClientsMixin:
         Delegates to the generalized static-enumeration serializer
         (:func:`stateright_tpu.semantics.device.device_serializable`):
         exact for any thread count / op bound whose interleaving count
-        stays under ``semantics.device.MAX_PATTERNS_EXACT`` (the pattern
-        axis chunks under ``lax.scan`` past the single-shot budget);
+        stays under ``semantics.device.MAX_PATTERNS_EXACT`` (past the
+        single-shot pattern budget it searches the progress lattice);
         larger shapes pass ``pattern_limit`` (a one-sided sampled pass)
         and declare the property in ``host_verified_properties``.
 

@@ -106,8 +106,8 @@ class PackedSingleCopyRegister(reg.PackedClientsMixin, PackedModelAdapter):
     via the static interleaving enumeration
     (:mod:`stateright_tpu.semantics.device`, SURVEY §7 M4 variant (b)) —
     EXACTLY while the client count keeps the enumeration under
-    ``MAX_PATTERNS_EXACT`` (<= 4 clients at 2 ops each; the pattern axis
-    chunks under ``lax.scan`` past the single-shot budget); beyond that
+    ``MAX_PATTERNS_EXACT`` (<= 4 clients at 2 ops each; past the
+    single-shot pattern budget it searches the progress lattice); beyond that
     the model declares ``host_verified_properties`` and the device runs a
     diverse sampled one-sided pass with exact host confirmation of flagged
     rows (variant (a)). With one server the model reaches full coverage (93
@@ -139,7 +139,7 @@ class PackedSingleCopyRegister(reg.PackedClientsMixin, PackedModelAdapter):
         self._consistency = consistency
         self._prop_name = self._inner.properties()[0].name
         # Device-exact serialization checking scales to the interleaving
-        # budget (chunked lax.scan past the single-shot lane limit); past
+        # budget (the progress lattice past the single-shot lane limit); past
         # it — or with ``device_exact=False`` — the property runs as a
         # conservative device pass (a diverse pattern subsample — True
         # proves serializability) with exact host confirmation of the

@@ -37,13 +37,16 @@ Semantics replicated (differentially tested against the host serializer):
 
 Sizing: P = (T·(M+1))! / ((M+1)!)^T — 20 at 2×2, 1 680 at 3×2, 34 650 at
 3×3, 369 600 at 4×2. Up to ``MAX_PATTERNS`` the whole enumeration runs as
-one ``[P]``-lane pipeline; past it (SURVEY §7 M4 variant (b) widened,
-round 4) the pattern axis is CHUNKED under ``lax.scan`` — live memory is
-bounded by one ``[chunk]`` block while exactness is preserved — up to
-``MAX_PATTERNS_EXACT``. Only beyond that (5 threads × 2 ops = 1.68e8)
-should models fall back to the engine's ``host_verified_properties`` path
-(a conservative sampled device predicate + exact host confirmation,
-xla.py M4 variant (a)).
+one ``[P]``-lane pipeline. Past it the exact check searches the progress
+lattice instead (``_lattice_serializable``): a pattern is a path through
+``{0..M+1}^T``, so reachability over its (M+2)^T nodes and the running
+value decides every pattern at once — 256 nodes × 16 values at 4×2, where
+the enumeration took about 2 ms of device time a state on a TPU v5e. The
+pattern axis is CHUNKED under ``lax.scan`` only for a sampled
+pass (``pattern_limit`` past ``MAX_PATTERNS``). Beyond
+``MAX_PATTERNS_EXACT`` (5 threads × 2 ops = 1.68e8) models fall back to
+the engine's ``host_verified_properties`` path (a conservative sampled
+device predicate + exact host confirmation, xla.py M4 variant (a)).
 
 The pipeline carries per-thread RUNNING counts instead of precomputed
 ``slot``/``cnt_before`` tables: the only embedded constant is the
@@ -63,12 +66,21 @@ import numpy as np
 #: Single-shot lane budget: up to this many interleavings run as one
 #: [P]-lane pipeline with no scan overhead.
 MAX_PATTERNS = 50_000
-#: Exact-enumeration ceiling for the chunked (lax.scan) path. Time-bounded,
-#: not memory-bound: each scan step evaluates one PATTERN_CHUNK block.
+#: Interleavings past which no exact device check is made: models
+#: declare ``host_verified_properties`` instead.
 MAX_PATTERNS_EXACT = 2_000_000
-#: Pattern-block width for the scanned path: live intermediates are
-#: [batch, PATTERN_CHUNK] lanes.
+#: Pattern-block width for the scanned (sampled) path: live intermediates
+#: are [batch, PATTERN_CHUNK] lanes.
 PATTERN_CHUNK = 8_192
+#: Frontier rows times serializer lanes (:func:`row_lanes`) that one
+#: property block evaluates (:func:`block_rows`). The pattern
+#: enumeration's temporaries grow with both: at 3 threads (1,680 lanes)
+#: about 0.6 MB a row (2.48 GB at 4,096 rows by XLA's memory analysis of
+#: the vmapped tester for a TPU v5e), so its 1,024-row blocks stay near
+#: 0.6 GB beside the visited set and the action grid. The progress
+#: lattice takes about 5 KB a row (2.7 MB at 512 rows): there the width
+#: only trades the padding of a level's last block against loop trips.
+ROW_LANE_BUDGET = 1 << 21
 
 
 @lru_cache(maxsize=None)
@@ -145,6 +157,29 @@ def pattern_count(T: int, max_ops: int) -> int:
     return math.factorial(T * slots) // math.factorial(slots) ** T
 
 
+def row_lanes(hist, pattern_limit: int = None) -> int:
+    """Lanes :func:`device_serializable` holds per row at once: every
+    pattern up to ``MAX_PATTERNS``, one ``PATTERN_CHUNK`` of a sampled
+    pass past it, and the progress lattice's nodes times the value domain
+    where it searches the lattice (exact past ``MAX_PATTERNS``)."""
+    T, M = len(hist.thread_ids), hist.max_ops
+    P = pattern_count(T, M)
+    if pattern_limit is not None and pattern_limit < P:
+        return PATTERN_CHUNK if pattern_limit > MAX_PATTERNS else pattern_limit
+    if P <= MAX_PATTERNS:
+        return P
+    return (M + 2) ** T * value_domain(hist)
+
+
+def block_rows(hist, pattern_limit: int = None) -> int:
+    """The largest power of two of rows whose lanes (:func:`row_lanes`)
+    fit ``ROW_LANE_BUDGET``: the row-block width of a property stage that
+    runs this serializer (512 at 4 threads x 2 ops with 3-bit op codes,
+    1,024 at 3 threads, 65,536 at 2)."""
+    fit = ROW_LANE_BUDGET // row_lanes(hist, pattern_limit)
+    return 1 << max(fit.bit_length() - 1, 0)
+
+
 class DeviceRegister:
     """Device form of :class:`~stateright_tpu.semantics.register.Register`
     under the ``history_codecs`` convention (register.py:102-128): stored
@@ -195,6 +230,109 @@ class DeviceWORegister:
         return sem_ok, v
 
 
+def value_domain(hist) -> int:
+    """Running spec values the lattice search tracks: ``0 ..
+    2^(op_bits+1) - 1``. Both device specs map a stored op code ``o`` (a
+    field of ``op_bits + 1`` bits) to value ``o - 2`` and start at 0."""
+    return 1 << (hist.op_bits + 1)
+
+
+@lru_cache(maxsize=None)
+def progress_lattice(T: int, slots: int):
+    """The progress lattice of T threads of ``slots`` slots: node ``p``
+    counts the slots each thread has taken, and a serialization is a path
+    from ``(0,)*T`` to ``(slots,)*T`` that takes one slot a step. Returns,
+    for each layer ``k`` (nodes with ``sum(p) == k``), the edges leaving it
+    as ``(t, p)`` (thread ``t`` takes its slot ``p[t]``) with two 0/1
+    matrices: ``src[e, i]`` (edge ``e`` leaves node ``i`` of layer k) and
+    ``dst[e, j]`` (it enters node ``j`` of layer k+1)."""
+    nodes = list(itertools.product(range(slots + 1), repeat=T))
+    layers = [[p for p in nodes if sum(p) == k] for k in range(T * slots + 1)]
+    out = []
+    for k in range(T * slots):
+        index = {p: j for j, p in enumerate(layers[k + 1])}
+        edges = [(t, p) for p in layers[k] for t in range(T) if p[t] < slots]
+        src = np.zeros((len(edges), len(layers[k])), np.float32)
+        dst = np.zeros((len(edges), len(layers[k + 1])), np.float32)
+        for e, (t, p) in enumerate(edges):
+            src[e, layers[k].index(p)] = 1
+            dst[e, index[p[:t] + (p[t] + 1,) + p[t + 1:]]] = 1
+        out.append((edges, src, dst))
+    return out
+
+
+def _lattice_serializable(hist, spec, real_time, N, FL, OP, RET, PRE, FLPRE):
+    """Exact serializability by reachability over the progress lattice
+    (:func:`progress_lattice`) and the spec's running value: the same
+    verdict as every pattern of :func:`interleaving_tids` at once, since a
+    pattern is a lattice path and each step's check depends only on the
+    node it leaves, the thread and the running value. Per row: a [nodes,
+    values] boolean state a layer, no pattern table. ``N``..``FLPRE`` are
+    the per-thread tables of :func:`device_serializable`."""
+    import jax
+    import jax.numpy as jnp
+
+    T = len(hist.thread_ids)
+    slots = hist.max_ops + 1
+    D = value_domain(hist)
+    u32 = jnp.uint32
+    zero = u32(0)
+    hi = jax.lax.Precision.HIGHEST
+
+    # What taking slot s of thread t checks, for every (t, s): [T, slots].
+    s_ = jnp.arange(slots, dtype=u32)[None, :]
+    is_comp = s_ < N[:, None]
+    is_fl = (s_ == N[:, None]) & (FL[:, None] != zero)
+    active = is_comp | is_fl
+    o = jnp.where(is_comp, OP, jnp.where(is_fl, FL[:, None], zero))
+    r = jnp.where(is_comp, RET, zero)
+    # Real-time prerequisites met at peer q's progress pq:
+    # pre_ok[t, s, q, pq], with sched[q, pq] = min(pq, N[q]).
+    b = jnp.where(
+        is_comp[..., None], PRE, jnp.where(is_fl[..., None], FLPRE[:, None, :], zero)
+    )
+    sched = jnp.minimum(jnp.arange(slots + 1, dtype=u32)[None, :], N[:, None])
+    pre_ok = (b[..., None] == zero) | (b[..., None] - u32(2) < sched[None, None])
+    if not real_time:
+        pre_ok = jnp.ones_like(pre_ok)
+
+    lattice = progress_lattice(T, slots)
+    edges = [e for layer in lattice for e in layer[0]]
+    # Each edge's (t, s) entry and its T prerequisite entries, picked by
+    # one-hot products (exact: 0/1 and small codes, HIGHEST precision).
+    pick_ts = np.zeros((T * slots, len(edges)), np.float32)
+    pick_pre = np.zeros((T * slots * T * (slots + 1), len(edges) * T), np.float32)
+    for e, (t, p) in enumerate(edges):
+        pick_ts[t * slots + p[t], e] = 1
+        for q in range(T):
+            pick_pre[((t * slots + p[t]) * T + q) * (slots + 1) + p[q], e * T + q] = 1
+    per_ts = jnp.stack([o, r, is_comp, active]).reshape(4, -1).astype(jnp.float32)
+    e_o, e_r, e_comp, e_active = jnp.dot(per_ts, pick_ts, precision=hi)
+    e_o, e_r = e_o.astype(u32), e_r.astype(u32)
+    e_comp, e_active = e_comp > 0.5, e_active > 0.5
+    e_pre = jnp.dot(pre_ok.reshape(-1).astype(jnp.float32), pick_pre, precision=hi)
+    e_rt = jnp.all(e_pre.reshape(len(edges), T) > 0.5, axis=1)
+
+    values = jnp.arange(D, dtype=u32)
+    reach = (values == spec.init_value(jnp, ()))[None, :]  # layer 0: one node
+    lo = 0
+    for edges_k, src, dst in lattice:
+        sl = slice(lo, lo + len(edges_k))
+        lo += len(edges_k)
+        sem_ok, nv = spec.step(
+            jnp, values[None, :], e_o[sl, None], e_r[sl, None], e_comp[sl, None]
+        )
+        act = e_active[sl, None]
+        ok = ~act | (e_rt[sl, None] & sem_ok)  # [E_k, D]
+        nv = jnp.where(act, nv, values[None, :])
+        here = jnp.dot(src, reach.astype(jnp.float32), precision=hi) > 0.5  # [E_k, D]
+        moved = jnp.any(
+            (here & ok)[..., None] & (nv[..., None] == values[None, None, :]), axis=1
+        )  # [E_k, D]
+        reach = jnp.dot(dst.T, moved.astype(jnp.float32), precision=hi) > 0.5
+    return jnp.any(reach)
+
+
 def device_serializable(hist, words, spec, *, real_time: bool, pattern_limit=None):
     """True iff the packed history in ``words`` admits a legal serialization
     of ``spec`` — the traced, exact device form of
@@ -235,8 +373,6 @@ def device_serializable(hist, words, spec, *, real_time: bool, pattern_limit=Non
         )
     L_ = hist.layout
     u32 = jnp.uint32
-    tid_np = interleaving_tids(T, slots, limit)  # [P, L] int8
-    P = tid_np.shape[0]
     Lsteps = T * slots
 
     N = jnp.stack([L_.get(words, f"h{t}_n") for t in range(T)])  # [T]
@@ -266,6 +402,14 @@ def device_serializable(hist, words, spec, *, real_time: bool, pattern_limit=Non
             for j in range(M):
                 PRE = PRE.at[t, j, q].set(L_.get(words, f"h{t}_pre", j * npeer + pi))
 
+    if limit is None and P_full > MAX_PATTERNS:
+        any_ok = _lattice_serializable(
+            hist, spec, real_time, N, FL, OP, RET, PRE, FLPRE
+        )
+        return (L_.get(words, "h_valid") != 0) & any_ok
+
+    tid_np = interleaving_tids(T, slots, limit)  # [P, L] int8
+    P = tid_np.shape[0]
     thread_lanes = jnp.arange(T, dtype=jnp.int32)
 
     def eval_block(tid_blk):
@@ -312,9 +456,9 @@ def device_serializable(hist, words, spec, *, real_time: bool, pattern_limit=Non
     if P <= MAX_PATTERNS:
         any_ok = jnp.any(eval_block(jnp.asarray(tid_np)))
     else:
-        # Chunk the pattern axis under lax.scan: exactness at bounded
-        # memory. The pad block repeats pattern 0 — duplicates cannot
-        # change an any() reduction.
+        # A sampled pass past the single-shot budget: chunk the pattern
+        # axis under lax.scan at bounded memory. The pad block repeats
+        # pattern 0 — duplicates cannot change an any() reduction.
         C = -(-P // PATTERN_CHUNK)
         pad = C * PATTERN_CHUNK - P
         if pad:

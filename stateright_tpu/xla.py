@@ -58,6 +58,11 @@ kernels over bit-packed uint32 state words:
   buffer per super-step and re-evaluates them on the host with the
   property's exact object-level condition (memoized serializer) before
   recording a discovery — SURVEY §7 M4 variant (a).
+- ``property_block_rows: int`` — optional. Rows per block of the property
+  stage, for models whose ``packed_properties`` needs memory per row (the
+  device serializer of ``semantics.device``). Where a bucket is wider, the
+  planes superstep evaluates the properties in blocks of this many rows,
+  over the blocks that hold live rows only (``blocked_properties``).
 """
 
 from __future__ import annotations
@@ -95,6 +100,38 @@ PACKED_ATTRS = (
     "packed_step",
     "packed_properties",
 )
+
+
+def blocked_properties(props_fn, frontier, f_count, block: int, neutral):
+    """``vmap(props_fn)`` over the first ``f_count`` rows of ``frontier``
+    only, ``block`` rows at a time: a device loop over ``ceil(f_count /
+    block)`` blocks, each a dynamic slice in and a dynamic update out.
+    Every row past ``f_count`` reads ``neutral`` (bool[P], the value that
+    flags nothing); blocks past the live rows are never evaluated."""
+    import jax
+    import jax.numpy as jnp
+
+    F = frontier.shape[0]
+
+    def body(i, out):
+        start = i * block
+        rows = jax.lax.dynamic_slice_in_dim(frontier, start, block)
+        props = jax.vmap(props_fn)(rows).astype(out.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(out, props, start, axis=0)
+
+    out = jnp.broadcast_to(neutral, (F, neutral.shape[0]))
+    out = jax.lax.fori_loop(0, (f_count + block - 1) // block, body, out)
+    live = jnp.arange(F) < f_count
+    return jnp.where(live[:, None], out, neutral)
+
+
+def property_rows(frontier: int, bucket: int, block: int) -> int:
+    """Rows the property stage evaluates in one level of ``frontier`` live
+    rows at ``bucket``: whole blocks over the live rows where the stage
+    runs blocked (``0 < block < bucket``), else the whole bucket."""
+    if 0 < block < bucket:
+        return -(-frontier // block) * block
+    return bucket
 
 
 def is_packed(model: Model) -> bool:
@@ -607,6 +644,12 @@ class XlaChecker(Checker):
             self._W = model.state_words
             self._A = model.max_actions
             self._P = len(self._properties)
+            # Rows per block of the planes superstep's property stage where
+            # a bucket is wider (``blocked_properties``); 0 evaluates the
+            # whole bucket in one vmap. The rows superstep never blocks.
+            self._prop_block = (
+                int(getattr(model, "property_block_rows", 0) or 0) if self._soa else 0
+            )
             # Host-verified properties: device flags candidates, host confirms
             # with the exact object-level condition (see module docstring).
             hv_names = frozenset(getattr(model, "host_verified_properties", ()))
@@ -1214,6 +1257,14 @@ class XlaChecker(Checker):
         max_probes = self._max_probes
         hv_cap = self._hv_cap
         ds = self._ds
+        prop_block = self._prop_block
+
+        def prop_neutral():
+            """Per property, the value that flags nothing: True for
+            ``always``, False for ``sometimes`` and ``eventually``."""
+            return jnp.asarray(
+                [p.expectation == Expectation.ALWAYS for p in self._properties], bool
+            )
 
         def dedup_words(words):
             return sym_canon(words) if symmetry else words
@@ -1459,9 +1510,17 @@ class XlaChecker(Checker):
                 dw = jax.vmap(dedup_words)(frontier)
                 fhi, flo = fphash.fingerprint_words(dw, jnp)
 
-            # 1. fused property evaluation over the frontier.
+            # 1. fused property evaluation over the frontier: in blocks of
+            #    live rows where the model's block width is under the bucket.
             with jax.named_scope("properties"):
-                props = jax.vmap(model.packed_properties)(frontier)  # [F, P]
+                if 0 < prop_block < f_cap:
+                    with jax.named_scope("serialize"):
+                        props = blocked_properties(
+                            model.packed_properties, frontier, f_count,
+                            prop_block, prop_neutral(),
+                        )
+                else:
+                    props = jax.vmap(model.packed_properties)(frontier)  # [F, P]
                 f_ebits, disc_found, disc_fp, (hv_words, hv_fps, hv_counts) = (
                     eval_properties(
                         props, f_valid, f_ebits, fhi, flo, disc_found, disc_fp,
@@ -2344,6 +2403,18 @@ class XlaChecker(Checker):
             f_in, e_in = self._frontier, self._frontier_ebits
         return f_in, e_in
 
+    def _property_counts(self, levels) -> Dict[str, int]:
+        """``property_rows``: rows the property stage evaluated over the
+        ``level_log`` rows ``levels``; ``property_block_rows``: its
+        row-block width where that is under the top bucket, else 0."""
+        block = self._prop_block
+        return {
+            "property_rows": sum(
+                property_rows(lv["frontier"], lv["bucket"], block) for lv in levels
+            ),
+            "property_block_rows": block if block < self._frontier_capacity else 0,
+        }
+
     def _pin_found_names(self) -> None:
         """Records first-found witness fingerprints by property name."""
         found = np.asarray(self._disc_found)
@@ -2531,6 +2602,10 @@ class XlaChecker(Checker):
                             }
                             for i in range(committed)
                         )
+                    if self._tracer.enabled:
+                        _sp.set(**self._property_counts(
+                            self.level_log[len(self.level_log) - committed:]
+                        ))
                     self._depth += committed
                     if committed:
                         self._max_depth = max(self._max_depth, self._depth - 1)
@@ -2696,6 +2771,8 @@ class XlaChecker(Checker):
                             ),
                         }
                     )
+                    if self._tracer.enabled:
+                        _sp.set(**self._property_counts(self.level_log[-1:]))
                     self._frontier, self._frontier_ebits, self._table = nf, ne, table
                     self._frontier_count = int(ncount)
                     self._disc_found, self._disc_fp = dfound, dfp
@@ -2861,6 +2938,9 @@ class XlaChecker(Checker):
             "dispatches": len(self.dispatch_log),
             "levels_committed": sum(c for _, c in self.dispatch_log),
             "cand_retries": self.cand_retries,
+            # Rows the property stage evaluated over the committed levels,
+            # and its row-block width (0: whole buckets).
+            **self._property_counts(self.level_log),
             "hv": dict(self.hv_stats),
             # -- event counters (obs.Counters, pre-seeded) ------------
             **self._counters.snapshot(),

@@ -135,8 +135,9 @@ def test_four_client_host_verified_finds_real_counterexample():
 @pytest.mark.slow
 def test_four_client_device_exact_bounded_parity():
     # The round-4 widened regime: 4 clients checked device-EXACT (369,600
-    # interleavings, chunked under lax.scan) with no host fallback —
-    # bounded-depth counts match the oracle and nothing is flagged.
+    # interleavings, decided by the progress-lattice search) with no host
+    # fallback — bounded-depth counts match the oracle and nothing is
+    # flagged.
     m = PackedSingleCopyRegister(4, 1)
     assert not getattr(m, "host_verified_properties", None)
     c = (

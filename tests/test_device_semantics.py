@@ -145,8 +145,8 @@ def _device_verdicts(histories, T, M, op_bits, ret_bits, op_code, ret_code, spec
         (2, 2, 250),
         (3, 2, 250),
         (3, 3, 40),
-        # 4x2 = 369,600 patterns: exercises the round-4 CHUNKED (lax.scan)
-        # exact path — past the single-shot MAX_PATTERNS budget.
+        # 4x2 = 369,600 patterns: past the single-shot MAX_PATTERNS budget,
+        # the exact check searches the progress lattice.
         (4, 2, 8),
     ],
 )
@@ -231,3 +231,17 @@ def test_seqcst_is_weaker_than_linearizability():
     lin = _device_verdicts([h], 2, 2, 3, 3, op_code, ret_code, DeviceRegister(), True)
     sc = _device_verdicts([s], 2, 2, 3, 3, op_code, ret_code, DeviceRegister(), False)
     assert not lin[0] and sc[0]
+
+
+@pytest.mark.parametrize("T,M,trials", [(2, 2, 120), (3, 2, 120), (3, 3, 40), (4, 2, 120)])
+@pytest.mark.parametrize("real_time", [True, False], ids=["lin", "seqcst"])
+def test_lattice_search_matches_host_serializer(T, M, trials, real_time, monkeypatch):
+    """The progress-lattice search at every shape (``MAX_PATTERNS`` set to
+    0 sends each exact check there), Register and WORegister specs: the
+    host serializer's verdict on every fuzzed history."""
+    import stateright_tpu.semantics.device as device
+
+    monkeypatch.setattr(device, "MAX_PATTERNS", 0)
+    test_register_fuzz_matches_host_serializer(T, M, trials, real_time)
+    if M == 2 and T < 4:
+        test_wo_register_fuzz_matches_host_serializer(T, M, trials, real_time)

@@ -73,6 +73,9 @@ METRIC_KEYS = {
     # time-series config gauge (docs/observability.md "Time series").
     "metrics_to",
 }
+#: Keys the single-chip engine adds: the property stage's row counters
+#: (docs/observability.md "checker.metrics()").
+SINGLE_CHIP_METRIC_KEYS = {"property_rows", "property_block_rows"}
 
 #: The metrics time-series row schema (exactly these keys;
 #: docs/observability.md "Time series" — promexport, the dashboard, and
@@ -423,8 +426,12 @@ def test_metrics_keys_across_dedups(dedup):
     c = _spawn(dedup=dedup).join()
     m = c.metrics()
     assert METRIC_KEYS <= set(m), METRIC_KEYS - set(m)
+    assert SINGLE_CHIP_METRIC_KEYS <= set(m)
     assert m["engine"] == "xla"
     assert m["dedup"] == dedup
+    # 2pc declares no property block: whole buckets every level.
+    assert m["property_block_rows"] == 0
+    assert m["property_rows"] == sum(lv["bucket"] for lv in c.level_log)
     assert m["state_count"] == c.state_count() == 1146
     assert m["unique_state_count"] == 288
     assert m["dispatches"] == len(c.dispatch_log)
