@@ -17,8 +17,14 @@ simultaneously:
 - in-batch dedup with the same determinism rule as the hash insert (the
   lowest original batch index wins: the original index is the sort's
   tie-break key),
-- the merge (survivors are already in key order; a stable compaction
-  restores the dense sorted prefix).
+- the merge (survivors are already in key order; a compaction sort
+  keyed on the survivors' own keys, every dropped row remapped to the
+  pad sentinel, restores the dense sorted prefix).
+
+Every key of the sort family's three sorts is unique (the ticket breaks
+the merge's ties, survivors' keys are distinct, the ``is_new`` route
+sorts tickets), so none of them asks for a stable sort: XLA:TPU lowers
+a stable sort with one more operand, an iota.
 
 It replaces the concurrent visited map of the reference's BFS core
 (``/root/reference/src/checker/bfs.rs:29-31, 349-363``) just like the
@@ -208,13 +214,17 @@ def insert(
         v64 = (
             jnp.concatenate([ss.val_hi, val_hi]).astype(jnp.uint64) << 32
         ) | jnp.concatenate([ss.val_lo, val_lo]).astype(jnp.uint64)
-        sk64, st, sv64 = jax.lax.sort((k64, ticket, v64), num_keys=2)
+        sk64, st, sv64 = jax.lax.sort(
+            (k64, ticket, v64), num_keys=2, is_stable=False
+        )
         skh = (sk64 >> 32).astype(jnp.uint32)
         skl = sk64.astype(jnp.uint32)
     elif via_sort:
         vh = jnp.concatenate([ss.val_hi, val_hi])
         vl = jnp.concatenate([ss.val_lo, val_lo])
-        skh, skl, st, svh, svl = jax.lax.sort((kh, kl, ticket, vh, vl), num_keys=3)
+        skh, skl, st, svh, svl = jax.lax.sort(
+            (kh, kl, ticket, vh, vl), num_keys=3, is_stable=False
+        )
     else:
         skh, skl, st = jax.lax.sort((kh, kl, ticket), num_keys=3)
 
@@ -231,13 +241,17 @@ def insert(
     new_n = jnp.sum(keep, dtype=jnp.int32)
     overflow = new_n > cap
 
-    # Stable compaction of survivors to the front keeps them key-sorted.
+    # Compaction of survivors to the front, in key order: the sort
+    # family keys it on the survivors' own (unique) keys with every
+    # dropped row remapped to the never-real all-ones key, so no
+    # stability is needed; ties among dropped rows land past new_n,
+    # where row_ok zeroes them.
     row_ok = jnp.arange(cap) < jnp.minimum(new_n, cap)
     z = jnp.uint32(0)
     if via_packed:
-        ckey = jnp.where(keep, jnp.int32(0), jnp.int32(1))
-        _, ck64, cv64 = jax.lax.sort(
-            (ckey, sk64, sv64), num_keys=1, is_stable=True
+        ck64, cv64 = jax.lax.sort(
+            (jnp.where(keep, sk64, ~jnp.uint64(0)), sv64),
+            num_keys=1, is_stable=False,
         )
         nkh = jnp.where(row_ok, (ck64[:cap] >> 32).astype(jnp.uint32), z)
         nkl = jnp.where(row_ok, ck64[:cap].astype(jnp.uint32), z)
@@ -245,10 +259,10 @@ def insert(
         nvl = jnp.where(row_ok, cv64[:cap].astype(jnp.uint32), z)
     elif via_sort:
         # Payload-through-sort: the compaction permutation moves every
-        # plane inside one more sort (keep-rank is the key), no gathers.
-        ckey = jnp.where(keep, jnp.int32(0), jnp.int32(1))
-        _, ckh, ckl, cvh, cvl = jax.lax.sort(
-            (ckey, skh, skl, svh, svl), num_keys=1, is_stable=True
+        # plane inside one more sort, no gathers.
+        ckh, ckl, cvh, cvl = jax.lax.sort(
+            (jnp.where(keep, skh, full), jnp.where(keep, skl, full), svh, svl),
+            num_keys=2, is_stable=False,
         )
         nkh = jnp.where(row_ok, ckh[:cap], z)
         nkl = jnp.where(row_ok, ckl[:cap], z)
@@ -267,12 +281,20 @@ def insert(
 
     # Route is_new back to original batch order.
     if via_sort:
-        # Scatter-free: sorting (ticket, winner) by ticket is the inverse
-        # permutation; candidate lanes are the tail cap:.
-        _, winner_in_order = jax.lax.sort(
-            (st, winner.astype(jnp.int32)), num_keys=1
+        # Scatter-free: sorting by ticket is the inverse permutation;
+        # candidate lanes are the tail cap:. The winner flag rides in the
+        # ticket's low bit, so one int32 operand carries both: 2^30 lanes
+        # at most, past what one chip's memory holds. (A uint32 route
+        # read a 17.7 MB higher device peak at 2pc rm=8 on a TPU v5e.)
+        if cap + m > 1 << 30:
+            raise ValueError(
+                f"sorted-set insert of {cap + m} lanes: the is_new route "
+                "packs (ticket << 1) | winner into an int32, 2^30 lanes at most"
+            )
+        routed = jax.lax.sort(
+            (st << 1) | winner.astype(jnp.int32), is_stable=False
         )
-        is_new = winner_in_order[cap:].astype(jnp.bool_)
+        is_new = (routed[cap:] & 1).astype(jnp.bool_)
     else:
         # Winner tickets are unique, so the scatter is conflict-free;
         # non-winners are routed out of range.
@@ -357,8 +379,10 @@ def insert_lane_words(ss: SortedSet, m: int) -> int:
     with an ``m``-lane batch at this table's capacity — the engine's
     cost-law telemetry (round-5 law: per-level time ~ sorted lane-words
     x log^2 n). Counts sort operands only; post-sort gathers and the
-    scatter ``is_new`` route are not sorted lanes. Tracks the same
-    trace-time lowering knobs the insert resolves."""
+    scatter ``is_new`` route are not sorted lanes. Counts the operands
+    as traced: the sort family's sorts are unstable, so XLA:TPU adds no
+    iota to them. Tracks the same trace-time lowering knobs the insert
+    resolves."""
     cap = ss.capacity
     if INSERT_VIA == "pallas" and _pallas_insert_block(cap, m):
         # Batch-scale only: 5-operand presort + 2-operand inverse.
@@ -366,9 +390,10 @@ def insert_lane_words(ss: SortedSet, m: int) -> int:
     n = cap + m
     if _via_sort():
         # Packed or pair, the sorted WORDS agree (packed trades operand
-        # streams, not bytes): 5-word merge + 5-word compaction + 2-word
-        # inverse permutation.
-        return n * 12
+        # streams, not bytes): 5-word merge + 4-word compaction + the
+        # inverse permutation, one word with the winner in the ticket's
+        # low bit. All three sorts are unstable.
+        return n * 10
     # Gather family: 3-operand merge + 2-operand compaction argsort;
     # values and is_new move by gather/scatter.
     return n * 5

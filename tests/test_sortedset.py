@@ -110,7 +110,14 @@ def _counts(c):
     return c.state_count(), c.unique_state_count(), c.max_depth()
 
 
-def test_engine_parity_two_phase_commit():
+@pytest.fixture(params=["auto", "sort"])
+def values_via(request, monkeypatch):
+    """The sorted set's insert lowering: the platform's own ("auto", the
+    gather family on the CPU) and the sort family a TPU takes."""
+    monkeypatch.setattr(sortedset, "VALUES_VIA", request.param)
+
+
+def test_engine_parity_two_phase_commit(values_via):
     from stateright_tpu.models.two_phase_commit import PackedTwoPhaseSys
 
     a = PackedTwoPhaseSys(3).checker().spawn_xla(dedup="hash").join()
@@ -134,7 +141,7 @@ def test_gather_compact_cap_exceeds_mask_length():
     assert _counts(c) == (1146, 288, 11)
 
 
-def test_engine_parity_under_forced_growth():
+def test_engine_parity_under_forced_growth(values_via):
     """Tiny capacities force the overflow-retry + growth path of both
     structures (sorted growth = plane copy, hash growth = rehash)."""
     from stateright_tpu.models.two_phase_commit import PackedTwoPhaseSys
@@ -145,7 +152,7 @@ def test_engine_parity_under_forced_growth():
     assert _counts(a) == _counts(b) == (1146, 288, 11)
 
 
-def test_engine_parity_discovery_model():
+def test_engine_parity_discovery_model(values_via):
     """A model with a real counterexample: discovery names and witness
     paths must agree across dedup structures."""
     from stateright_tpu.models.single_copy_register import PackedSingleCopyRegister
@@ -158,7 +165,7 @@ def test_engine_parity_discovery_model():
         assert len(da[name]) == len(db[name])
 
 
-def test_engine_parity_symmetry():
+def test_engine_parity_symmetry(values_via):
     from stateright_tpu.models.increment import PackedIncrement
 
     a = PackedIncrement(3).checker().symmetry().spawn_xla(dedup="hash").join()
@@ -205,23 +212,146 @@ def test_fingerprint_planes_matches_words():
         assert np.array_equal(wl, np.asarray(jl))
 
 
-def test_insert_values_via_sort_matches_gather(monkeypatch):
+def _distinct_keys(rng, n):
+    hi = jnp.asarray(rng.permutation(np.arange(1, n + 1, dtype=np.uint32)))
+    lo = jnp.asarray(rng.integers(1, 2**32 - 1, n, dtype=np.uint32))
+    return hi, lo
+
+
+def _keyed_batch(rng, hi, lo):
+    m = hi.shape[0]
+    vh = jnp.asarray(rng.integers(0, 2**32, m, dtype=np.uint32))
+    vl = jnp.asarray(rng.integers(0, 2**32, m, dtype=np.uint32))
+    return hi, lo, vh, vl, jnp.ones((m,), bool)
+
+
+def _lowering_case(case, rng):
+    """``(capacity, batches, last_n, last_overflow)`` for one case of the
+    sort-vs-gather differential; ``None`` leaves a final value unchecked."""
+    if case == "mixed":
+        return 1 << 11, [_rand_batch(rng, 257, 300) for _ in range(6)], None, False
+    if case == "duplicates":
+        # 7 x 7 keys: nearly every lane repeats an in-batch key or a stored one.
+        return 1 << 8, [_rand_batch(rng, 257, 8) for _ in range(5)], None, False
+    if case == "inactive":
+        hi, lo, vh, vl, _ = _rand_batch(rng, 257, 300)
+        off = (hi, lo, vh, vl, jnp.zeros((257,), bool))
+        return 1 << 11, [off, _rand_batch(rng, 257, 300), off], None, False
+    hi, lo = _distinct_keys(rng, 400)
+    if case == "full":
+        # 256 uniques into 2^8 slots, with in-batch and cross-table repeats.
+        b1 = _keyed_batch(rng, jnp.concatenate([hi[:150], hi[:50]]),
+                          jnp.concatenate([lo[:150], lo[:50]]))
+        b2 = _keyed_batch(rng, jnp.concatenate([hi[150:256], hi[:94]]),
+                          jnp.concatenate([lo[150:256], lo[:94]]))
+        return 1 << 8, [b1, b2], 1 << 8, False
+    assert case == "overflow"  # 400 uniques against 2^8 slots
+    b1 = _keyed_batch(rng, hi[:200], lo[:200])
+    b2 = _keyed_batch(rng, hi[200:], lo[200:])
+    return 1 << 8, [b1, b2], 1 << 8, True
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["mixed", "duplicates", "inactive", "full", "overflow", "packed"],
+)
+def test_insert_values_via_sort_matches_gather(monkeypatch, case):
     """The payload-through-sort insert lowering is bit-identical to the
     gather lowering (STPU_SORTEDSET_VALUES; which is faster is a hardware
-    question, correctness is not)."""
+    question, correctness is not): its three unstable sorts hold on
+    duplicate-heavy and all-inactive batches, a table filled to capacity,
+    an overflowing batch, and packed u64 keys."""
+    import jax
+
     rng = np.random.default_rng(23)
-    ss_a = sortedset.make(1 << 11, jnp)
-    ss_b = sortedset.make(1 << 11, jnp)
-    for rnd in range(6):
-        hi, lo, vh, vl, act = _rand_batch(rng, 257, 300)
-        monkeypatch.setattr(sortedset, "VALUES_VIA", "gather")
-        ss_a, new_a, ovf_a = sortedset.insert(ss_a, hi, lo, vh, vl, act)
-        monkeypatch.setattr(sortedset, "VALUES_VIA", "sort")
-        ss_b, new_b, ovf_b = sortedset.insert(ss_b, hi, lo, vh, vl, act)
-        for a, b in zip(ss_a, ss_b):
-            assert np.array_equal(np.asarray(a), np.asarray(b)), rnd
-        assert np.array_equal(np.asarray(new_a), np.asarray(new_b)), rnd
-        assert bool(ovf_a) == bool(ovf_b)
+    base = "mixed" if case == "packed" else case
+    cap, batches, last_n, last_ovf = _lowering_case(base, rng)
+    prev_x64 = jax.config.jax_enable_x64
+    if case == "packed":
+        jax.config.update("jax_enable_x64", True)
+    try:
+        ss_a = sortedset.make(cap, jnp)
+        ss_b = sortedset.make(cap, jnp)
+        for rnd, (hi, lo, vh, vl, act) in enumerate(batches):
+            monkeypatch.setattr(sortedset, "VALUES_VIA", "gather")
+            monkeypatch.setattr(sortedset, "KEYS_VIA", "pair")
+            ss_a, new_a, ovf_a = sortedset.insert(ss_a, hi, lo, vh, vl, act)
+            monkeypatch.setattr(sortedset, "VALUES_VIA", "sort")
+            if case == "packed":
+                monkeypatch.setattr(sortedset, "KEYS_VIA", "packed")
+            ss_b, new_b, ovf_b = sortedset.insert(ss_b, hi, lo, vh, vl, act)
+            for a, b in zip(ss_a, ss_b):
+                assert np.array_equal(np.asarray(a), np.asarray(b)), rnd
+            assert np.array_equal(np.asarray(new_a), np.asarray(new_b)), rnd
+            assert bool(ovf_a) == bool(ovf_b)
+            if not bool(np.any(np.asarray(act))):
+                assert not bool(np.any(np.asarray(new_b))), rnd
+        if last_n is not None:
+            assert int(ss_b.n) == last_n
+        assert bool(ovf_b) == last_ovf
+    finally:
+        jax.config.update("jax_enable_x64", prev_x64)
+
+
+@pytest.mark.parametrize(
+    "keys_via, operands", [("pair", [5, 4, 1]), ("packed", [3, 2, 1])]
+)
+def test_insert_sort_family_lowers_to_unstable_sorts(
+    monkeypatch, keys_via, operands
+):
+    """The sort family's three sorts carry 5, 4 and 1 operands on u32
+    pairs (3, 2 and 1 on packed u64 lanes) and none is stable (XLA:TPU
+    lowers a stable sort with one more operand, an iota), and
+    ``insert_lane_words`` counts exactly the pair lowering's operands."""
+    import re
+
+    import jax
+
+    monkeypatch.setattr(sortedset, "VALUES_VIA", "sort")
+    monkeypatch.setattr(sortedset, "KEYS_VIA", keys_via)
+    cap, m = 1 << 8, 96
+    prev_x64 = jax.config.jax_enable_x64
+    if keys_via == "packed":
+        jax.config.update("jax_enable_x64", True)
+    try:
+        ss = sortedset.make(cap, jnp)
+        z = jnp.zeros((m,), jnp.uint32)
+        text = jax.jit(sortedset.insert).lower(
+            ss, z, z, z, z, jnp.zeros((m,), bool)
+        ).as_text()
+        lane_words = sortedset.insert_lane_words(ss, m)
+    finally:
+        jax.config.update("jax_enable_x64", prev_x64)
+    sorts = re.findall(r'"stablehlo\.sort"\(([^)]*)\) <\{([^}]*)\}>', text)
+    assert [len(ops.split(",")) for ops, _ in sorts] == operands
+    assert all("is_stable = false" in attrs for _, attrs in sorts)
+    assert lane_words == (5 + 4 + 1) * (cap + m)
+
+
+def test_insert_route_refuses_past_int32(monkeypatch):
+    """The sort family's ``is_new`` route packs ``(ticket << 1) | winner``
+    into an int32, so an insert past 2^30 lanes is refused at trace time
+    (abstractly traced here: nothing is allocated)."""
+    import jax
+
+    monkeypatch.setattr(sortedset, "VALUES_VIA", "sort")
+    monkeypatch.setattr(sortedset, "KEYS_VIA", "pair")
+    plane = jax.ShapeDtypeStruct((1 << 30,), jnp.uint32)
+    ss = sortedset.SortedSet(
+        plane, plane, plane, plane, jax.ShapeDtypeStruct((), jnp.int32)
+    )
+    lane = jax.ShapeDtypeStruct((1,), jnp.uint32)
+    act = jax.ShapeDtypeStruct((1,), jnp.bool_)
+    with pytest.raises(ValueError, match="2\\^30 lanes"):
+        jax.eval_shape(sortedset.insert, ss, lane, lane, lane, lane, act)
+    small = sortedset.SortedSet(
+        *(jax.ShapeDtypeStruct((1 << 29,), jnp.uint32),) * 4,
+        jax.ShapeDtypeStruct((), jnp.int32),
+    )
+    _, is_new, _ = jax.eval_shape(
+        sortedset.insert, small, lane, lane, lane, lane, act
+    )
+    assert is_new.shape == (1,)
 
 
 def test_engine_compaction_lowerings_match():
